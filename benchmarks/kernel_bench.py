@@ -1,9 +1,7 @@
-"""Kernel micro-benchmarks.
-
-CPU container: wall-times are interpret-mode/oracle timings (the Pallas
-kernels target TPU); the meaningful numbers here are the *roofline
-estimates* computed from kernel arithmetic (MXU flops, VMEM traffic) for
-the TPU target, plus oracle wall-times as a regression canary."""
+"""Oracle micro-benchmarks: wall time of the pure-jnp references in
+``repro.kernels.ref`` (not the Pallas kernels) on whatever device JAX
+picks.  Every result is labelled with that device's platform and kind;
+none is a TPU number unless the label says ``tpu``."""
 
 import time
 
@@ -13,9 +11,6 @@ import jax.numpy as jnp
 from repro.kernels import ref
 
 from .common import csv_line, dump
-
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
 
 
 def _time(fn, *args, iters=3):
@@ -28,9 +23,13 @@ def _time(fn, *args, iters=3):
 
 
 def main() -> dict:
-    out = {}
+    dev = jax.devices()[0]
+    device = f"{dev.platform}:{dev.device_kind}"
+    out = {"device": {"platform": dev.platform, "kind": dev.device_kind,
+                      "count": len(jax.devices())}}
     key = jax.random.PRNGKey(0)
-    print("kernel          M/B   K/S   N/hd  oracle_us  tpu_est_us  bound")
+    print(f"oracle wall times on {device}")
+    print("oracle          M/B   K/S   N/hd  us_per_call")
     for (M, K, N) in [(256, 512, 512), (1024, 1024, 1024),
                       (128, 4096, 4096)]:
         k1, k2 = jax.random.split(key)
@@ -39,17 +38,10 @@ def main() -> dict:
         sw = jnp.full((N,), 0.01, jnp.float32)
         us = _time(lambda a, b: ref.imc_mvm_ref(a, b, jnp.float32(0.1), sw),
                    qx, qw)
-        flops = 2.0 * M * K * N
-        bytes_ = M * K + K * N + 4 * M * N
-        t_c = flops / PEAK_FLOPS * 1e6
-        t_m = bytes_ / HBM_BW * 1e6
-        bound = "compute" if t_c > t_m else "memory"
-        est = max(t_c, t_m)
-        name = f"imc_mvm.{M}x{K}x{N}"
-        print(f"imc_mvm    {M:6d} {K:5d} {N:5d} {us:10.1f} {est:11.2f}"
-              f"  {bound}")
-        csv_line(name, us, f"tpu_est={est:.2f}us,{bound}-bound")
-        out[name] = {"oracle_us": us, "tpu_est_us": est, "bound": bound}
+        name = f"imc_mvm_ref.{M}x{K}x{N}"
+        print(f"imc_mvm_ref {M:6d} {K:5d} {N:5d} {us:12.1f}")
+        csv_line(name, us, f"device={device}")
+        out[name] = {"oracle_us": us}
 
     for (B, H, S, hd) in [(2, 8, 1024, 128), (1, 8, 4096, 128)]:
         ks = jax.random.split(key, 3)
@@ -57,17 +49,10 @@ def main() -> dict:
         k = jax.random.normal(ks[1], (B, H, S, hd), jnp.float32)
         v = jax.random.normal(ks[2], (B, H, S, hd), jnp.float32)
         us = _time(lambda a, b, c: ref.flash_attention_ref(a, b, c), q, k, v)
-        flops = 4.0 * B * H * S * S * hd
-        bytes_ = 2 * (3 * B * H * S * hd + B * H * S * hd)
-        t_c = flops / PEAK_FLOPS * 1e6
-        t_m = bytes_ / HBM_BW * 1e6
-        est = max(t_c, t_m)
-        bound = "compute" if t_c > t_m else "memory"
-        name = f"flash.{B}x{H}x{S}x{hd}"
-        print(f"flash      {B:3d}x{H}  {S:5d} {hd:5d} {us:10.1f} {est:11.2f}"
-              f"  {bound}")
-        csv_line(name, us, f"tpu_est={est:.2f}us,{bound}-bound")
-        out[name] = {"oracle_us": us, "tpu_est_us": est, "bound": bound}
+        name = f"flash_ref.{B}x{H}x{S}x{hd}"
+        print(f"flash_ref  {B:3d}x{H}  {S:5d} {hd:5d} {us:12.1f}")
+        csv_line(name, us, f"device={device}")
+        out[name] = {"oracle_us": us}
 
     path = dump("kernel_bench", out)
     print(f"artifact: {path}")

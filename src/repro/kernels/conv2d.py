@@ -10,12 +10,18 @@ K x K tap accumulation of MXU matmuls over the full spatial map:
 Accumulation is INT32 (exact), with fused per-channel requantization in
 the epilogue — bit-compatible with ``repro.models.quant.quantized_conv2d``.
 
-Scope: SAME padding, stride 1/2, spatial maps that fit VMEM as one block
-(the paper's CIFAR-scale workloads; 34x34x512 int8 = 0.6 MB).  Larger
-maps (YOLO 640x640 early layers) use the jnp oracle / XLA conv — see
-ops.py dispatch.
+Strides above 1 are split into stride phases by the wrapper: phase
+``(a, b)`` holds ``xpad[a::s, b::s]``, so tap ``(di, dj)`` is the unit-stride
+window of phase ``(di % s, dj % s)`` at offset ``(di // s, dj // s)``.  The
+kernel then takes only unit-stride slices, which Mosaic requires.  The int8
+operands go to the MXU as int8 (``preferred_element_type=int32``).
 
-Grid: (B, Cout/bn); x block (1, Hp, Wp, Cin); w block (K, K, Cin, bn).
+Scope: SAME padding, any stride, spatial maps that fit VMEM as one block
+(the paper's CIFAR-scale workloads; 34x34x512 int8 = 0.6 MB).  Larger maps
+(YOLO 640x640 early layers) need spatial tiling, which this kernel does not
+have; nothing routes them here.
+
+Grid: (B, Cout/bn); x block (1, s*s, Hq, Wq, Cin); w block (K, K, Cin, bn).
 """
 
 from __future__ import annotations
@@ -30,25 +36,31 @@ from jax.experimental import pallas as pl
 
 def _conv_kernel(x_ref, w_ref, sx_ref, sw_ref, b_ref, o_ref, *,
                  ksize: int, stride: int, h_out: int, w_out: int):
-    x = x_ref[0].astype(jnp.int32)             # (Hp, Wp, Cin)
+    cin = x_ref.shape[-1]
     acc = jnp.zeros((h_out * w_out, o_ref.shape[-1]), jnp.int32)
     for di in range(ksize):
         for dj in range(ksize):
-            tap = jax.lax.slice(
-                x,
-                (di, dj, 0),
-                (di + stride * (h_out - 1) + 1,
-                 dj + stride * (w_out - 1) + 1,
-                 x.shape[-1]),
-                (stride, stride, 1),
-            )                                   # (h_out, w_out, Cin)
-            tap2d = tap.reshape(h_out * w_out, x.shape[-1])
-            w_tap = w_ref[di, dj].astype(jnp.int32)   # (Cin, bn)
+            phase = (di % stride) * stride + dj % stride
+            oi, oj = di // stride, dj // stride
+            tap = x_ref[0, phase, oi:oi + h_out, oj:oj + w_out, :]
             acc += jax.lax.dot_general(
-                tap2d, w_tap, (((1,), (0,)), ((), ())),
+                tap.reshape(h_out * w_out, cin), w_ref[di, dj],
+                (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.int32)
     y = acc.astype(jnp.float32) * sx_ref[0, 0] * sw_ref[0, :] + b_ref[0, :]
     o_ref[...] = y.reshape(1, h_out, w_out, -1)
+
+
+def _stride_phases(xp: jnp.ndarray, stride: int) -> jnp.ndarray:
+    """(B, Hp, Wp, C) -> (B, s*s, ceil(Hp/s), ceil(Wp/s), C); phase
+    ``a*s + b`` holds ``xp[:, a::s, b::s]`` (zero-padded at the far edge)."""
+    B, Hp, Wp, C = xp.shape
+    hq, wq = -(-Hp // stride), -(-Wp // stride)
+    xp = jnp.pad(xp, ((0, 0), (0, hq * stride - Hp), (0, wq * stride - Wp),
+                      (0, 0)))
+    xp = xp.reshape(B, hq, stride, wq, stride, C)
+    return xp.transpose(0, 2, 4, 1, 3, 5).reshape(
+        B, stride * stride, hq, wq, C)
 
 
 @functools.partial(jax.jit,
@@ -77,14 +89,15 @@ def imc_conv2d(qx: jnp.ndarray, qw: jnp.ndarray, sx: jnp.ndarray,
     bias = bias if bias is not None else jnp.zeros((Cout,), jnp.float32)
     bp = bias if rem == 0 else jnp.pad(bias, (0, bn_ - rem))
     Np = wp.shape[-1]
-    Hp, Wp = xp.shape[1], xp.shape[2]
+    xs = _stride_phases(xp, stride)
+    P, Hq, Wq = xs.shape[1:4]
 
     out = pl.pallas_call(
         functools.partial(_conv_kernel, ksize=K, stride=stride,
                           h_out=h_out, w_out=w_out),
         grid=(B, Np // bn_),
         in_specs=[
-            pl.BlockSpec((1, Hp, Wp, Cin), lambda b, n: (b, 0, 0, 0)),
+            pl.BlockSpec((1, P, Hq, Wq, Cin), lambda b, n: (b, 0, 0, 0, 0)),
             pl.BlockSpec((K, K, Cin, bn_), lambda b, n: (0, 0, 0, n)),
             pl.BlockSpec((1, 1), lambda b, n: (0, 0)),
             pl.BlockSpec((1, bn_), lambda b, n: (0, n)),
@@ -94,6 +107,6 @@ def imc_conv2d(qx: jnp.ndarray, qw: jnp.ndarray, sx: jnp.ndarray,
                                lambda b, n: (b, 0, 0, n)),
         out_shape=jax.ShapeDtypeStruct((B, h_out, w_out, Np), jnp.float32),
         interpret=interpret,
-    )(xp, wp, jnp.asarray(sx, jnp.float32).reshape(1, 1),
+    )(xs, wp, jnp.asarray(sx, jnp.float32).reshape(1, 1),
       swp.reshape(1, -1).astype(jnp.float32), bp.reshape(1, -1))
     return out[..., :Cout]

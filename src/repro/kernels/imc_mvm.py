@@ -14,9 +14,11 @@ quantized CNN/MVM layers can swap implementations freely.
 Grid: (M/bm, N/bn, K/bk) with K innermost (accumulate in a VMEM f32/i32
 scratch); blocks default to MXU-aligned 128x128x128.
 
-This container is CPU-only: tests run the kernel with interpret=True
-(executes the same kernel body in Python) against the pure-jnp oracle in
-``ref.py``; on real TPU the same pallas_call compiles to MXU code.
+The operands reach the MXU as int8 (``preferred_element_type=int32``);
+Mosaic refuses an int32 x int32 dot.  The tests run the kernel with
+``interpret=True`` against the pure-jnp oracle in ``ref.py``;
+``tests/test_tpu_compile.py`` compiles it for a described v5e, and
+``chip_smoke.py`` runs it natively on one chip against the same oracle.
 """
 
 from __future__ import annotations
@@ -53,10 +55,8 @@ def _imc_mvm_kernel(x_ref, w_ref, sx_ref, sw_ref, b_ref, o_ref, acc_ref,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32)
-    w = w_ref[...].astype(jnp.int32)
     acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
+        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)

@@ -1,12 +1,10 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input-shape)
 on the production meshes, record memory/cost/collective analyses.
 
-The two lines above MUST stay first: jax locks the device count on first
-init, and the production meshes need 512 placeholder host devices.  Do
-not set that flag anywhere global (tests/benches see the real host).
+Run as a script, it appends ``--xla_force_host_platform_device_count=512``
+to ``XLA_FLAGS`` before JAX's backend starts: the production meshes need
+512 placeholder host devices.  Importing the module (the tests import
+``parse_collectives``) changes no flag.
 
 Per cell this produces (artifacts/dryrun/<arch>__<shape>__<mesh>.json):
   * compile success + wall time,
@@ -25,22 +23,21 @@ Usage:
   python -m repro.launch.dryrun --all [--multi-pod] [--force]
 """
 
-# The XLA env flag above must be set before anything imports jax,
-# hence module code precedes the imports.
-import argparse  # noqa: E402
-import dataclasses  # noqa: E402
-import json  # noqa: E402
-import re  # noqa: E402
-import time  # noqa: E402
-import traceback  # noqa: E402
-from typing import Dict, Optional, Tuple  # noqa: E402
+import argparse
+import dataclasses
+import json
+import os
+import re
+import time
+import traceback
+from typing import Dict, Optional, Tuple
 
-import jax  # noqa: E402
+import jax
 
-from repro.configs import SHAPES, all_archs, get_config  # noqa: E402
-from repro.configs.base import LMConfig, ShapeSpec, shape_supported  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
-from repro.models.lm import model, sharding  # noqa: E402
+from repro.configs import SHAPES, all_archs, get_config
+from repro.configs.base import LMConfig, ShapeSpec, shape_supported
+from repro.launch.mesh import make_production_mesh
+from repro.models.lm import model, sharding
 
 ART_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "artifacts", "dryrun")
@@ -302,4 +299,8 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    # read when the backend starts, which no import above does
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=512"
+                               ).strip()
     main()

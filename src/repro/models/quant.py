@@ -75,6 +75,17 @@ def int8_matmul_acc(qx: jnp.ndarray, qw: jnp.ndarray) -> jnp.ndarray:
     )
 
 
+def int8_conv_acc(qx: jnp.ndarray, qw: jnp.ndarray, stride: int = 1,
+                  padding: str = "SAME") -> jnp.ndarray:
+    """INT8 NHWC x HWIO conv -> INT32 exact accumulation."""
+    return jax.lax.conv_general_dilated(
+        qx.astype(jnp.int32), qw.astype(jnp.int32),
+        window_strides=(stride, stride), padding=padding,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        preferred_element_type=jnp.int32,
+    )
+
+
 def quantized_matmul(x: jnp.ndarray, w: jnp.ndarray,
                      b: Optional[jnp.ndarray] = None,
                      x_scale: Optional[jnp.ndarray] = None,
@@ -101,12 +112,7 @@ def quantized_conv2d(x: jnp.ndarray, w: jnp.ndarray,
     """INT8 conv via integer accumulate, NHWC/HWIO."""
     qx = quantize_act(x, x_scale)
     qw = quantize_weight(w, channel_axis=-1)
-    acc = jax.lax.conv_general_dilated(
-        qx.q.astype(jnp.int32), qw.q.astype(jnp.int32),
-        window_strides=(stride, stride), padding=padding,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        preferred_element_type=jnp.int32,
-    ).astype(jnp.float32)
+    acc = int8_conv_acc(qx.q, qw.q, stride, padding).astype(jnp.float32)
     if noise_std > 0.0 and key is not None:
         acc = acc + noise_std * jax.random.normal(key, acc.shape)
     y = acc * qx.scale * qw.scale
