@@ -1,0 +1,7 @@
+"""p90_ms: 90th percentile latency of a stream's frames, in milliseconds."""
+
+from bench.metrics._latency import percentile_ms
+
+
+def read(run):
+    return percentile_ms(run, 90)
