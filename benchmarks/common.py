@@ -4,8 +4,7 @@ All suites take their simulator from :func:`make_sim`, which honors the
 module-level ``SIM_MODE``: ``"periodic"`` by default (the compiled
 quantized loop with steady-state early exit — see
 ``repro.core.simulator``), overridable to ``"exact"`` or ``"reference"``
-via the ``REPRO_SIM_MODE`` environment variable or by assignment (the
-``sim_speed`` suite toggles it to measure honest before/after).
+via the ``REPRO_SIM_MODE`` environment variable or by assignment.
 """
 
 from __future__ import annotations

@@ -2,14 +2,10 @@
 
 This is the historical dict-keyed ``_run_streams`` implementation that
 ``simulator.py`` replaced with the compiled ``SimContext`` loop.  It is
-kept (verbatim, minus the module it lived in) for two jobs:
-
-* **equivalence oracle** — ``tests/test_sim_property.py`` drives random
-  DAGs x assignments x replica configs through both loops and asserts
-  bit-identical outputs, a far stronger net than the fixed goldens;
-* **honest speedup measurement** — ``benchmarks/sim_speed.py`` times
-  this loop against the compiled one on the real workloads and records
-  the ratio in ``BENCH_sim.json``.
+kept (verbatim, minus the module it lived in) as the **equivalence
+oracle**: ``tests/test_sim_property.py`` drives random DAGs x assignments
+x replica configs through both loops and asserts bit-identical outputs, a
+far stronger net than the fixed goldens.
 
 Do not "fix" or optimize this module: its value is being frozen.
 """

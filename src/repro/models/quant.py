@@ -14,14 +14,24 @@ Scheme (matches common IMC deployments and our Pallas ``imc_mvm`` kernel):
 All functions are pure-jnp and jit-safe; the Pallas kernel in
 ``repro.kernels.imc_mvm`` implements the same integer semantics on TPU
 and is tested against ``quantized_matmul`` bit-exactly.
+
+Spans (``repro.obs``, recorded only inside ``obs.recording()``):
+``quantized_conv2d`` and ``quantized_matmul`` time their four phases as
+``quant.act`` (activation scale if computed, the scale on the device,
+divide, round, clip, cast), ``quant.weight`` (``quantize_weight``),
+``int8.acc`` (the integer conv or dot with its casts) and ``dequant``
+(cast, optional noise, the two scale multiplies, bias).  Counter:
+``quant.weight.tensors``, one per weight tensor quantised.
 """
 
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Optional
+from typing import Dict, NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
+
+from repro import obs
 
 
 class QTensor(NamedTuple):
@@ -86,39 +96,53 @@ def int8_conv_acc(qx: jnp.ndarray, qw: jnp.ndarray, stride: int = 1,
     )
 
 
+def _quantize_operands(x, w, x_scale):
+    """The ``quant.act`` and ``quant.weight`` phases; ``x_scale`` is a
+    float, a 0-d array or None (then computed from ``x``)."""
+    with obs.span("quant.act"):
+        qx = quantize_act(
+            x, None if x_scale is None else jnp.asarray(x_scale, jnp.float32))
+    with obs.span("quant.weight"):
+        obs.count("quant.weight.tensors")
+        qw = quantize_weight(w, channel_axis=-1)
+    return qx, qw
+
+
+def _dequantize_acc(acc, qx, qw, b, noise_std, key):
+    """The ``dequant`` phase: int32 accumulator -> float32 output."""
+    with obs.span("dequant"):
+        acc = acc.astype(jnp.float32)
+        if noise_std > 0.0 and key is not None:
+            acc = acc + noise_std * jax.random.normal(key, acc.shape)
+        y = acc * qx.scale * qw.scale
+        if b is not None:
+            y = y + b
+    return y
+
+
 def quantized_matmul(x: jnp.ndarray, w: jnp.ndarray,
                      b: Optional[jnp.ndarray] = None,
-                     x_scale: Optional[jnp.ndarray] = None,
+                     x_scale: Optional[Union[float, jnp.ndarray]] = None,
                      noise_std: float = 0.0,
                      key: Optional[jax.Array] = None) -> jnp.ndarray:
     """Quantize -> integer matmul -> dequantize (+ optional AIMC noise)."""
-    qx = quantize_act(x, x_scale)
-    qw = quantize_weight(w, channel_axis=-1)
-    acc = int8_matmul_acc(qx.q, qw.q).astype(jnp.float32)
-    if noise_std > 0.0 and key is not None:
-        acc = acc + noise_std * jax.random.normal(key, acc.shape)
-    y = acc * qx.scale * qw.scale
-    if b is not None:
-        y = y + b
-    return y
+    qx, qw = _quantize_operands(x, w, x_scale)
+    with obs.span("int8.acc"):
+        acc = int8_matmul_acc(qx.q, qw.q)
+    return _dequantize_acc(acc, qx, qw, b, noise_std, key)
 
 
 def quantized_conv2d(x: jnp.ndarray, w: jnp.ndarray,
                      b: Optional[jnp.ndarray] = None,
                      stride: int = 1, padding: str = "SAME",
-                     x_scale: Optional[jnp.ndarray] = None,
+                     x_scale: Optional[Union[float, jnp.ndarray]] = None,
                      noise_std: float = 0.0,
                      key: Optional[jax.Array] = None) -> jnp.ndarray:
     """INT8 conv via integer accumulate, NHWC/HWIO."""
-    qx = quantize_act(x, x_scale)
-    qw = quantize_weight(w, channel_axis=-1)
-    acc = int8_conv_acc(qx.q, qw.q, stride, padding).astype(jnp.float32)
-    if noise_std > 0.0 and key is not None:
-        acc = acc + noise_std * jax.random.normal(key, acc.shape)
-    y = acc * qx.scale * qw.scale
-    if b is not None:
-        y = y + b
-    return y
+    qx, qw = _quantize_operands(x, w, x_scale)
+    with obs.span("int8.acc"):
+        acc = int8_conv_acc(qx.q, qw.q, stride, padding)
+    return _dequantize_acc(acc, qx, qw, b, noise_std, key)
 
 
 # ---------------------------------------------------------------------------
