@@ -17,11 +17,14 @@ and is tested against ``quantized_matmul`` bit-exactly.
 
 Spans (``repro.obs``, recorded only inside ``obs.recording()``):
 ``quantized_conv2d`` and ``quantized_matmul`` time their four phases as
-``quant.act`` (activation scale if computed, the scale on the device,
+``quant.act`` (activation scale if computed, the scale as a float32 array,
 divide, round, clip, cast), ``quant.weight`` (``quantize_weight``),
 ``int8.acc`` (the integer conv or dot with its casts) and ``dequant``
 (cast, optional noise, the two scale multiplies, bias).  Counter:
-``quant.weight.tensors``, one per weight tensor quantised.
+``quant.weight.tensors``, one per weight tensor quantised.  Called eagerly
+they time the device launches; inside a function ``jax.jit`` traces, as
+the graph executor's program, they time the tracing and the counter
+counts tensors quantised per trace, once per compiled program.
 """
 
 from __future__ import annotations

@@ -44,8 +44,9 @@ import numpy as np
 #: ``node`` per graph node, and the phases of an int8 conv or dense node
 SPAN_NAMES = ("execute", "node", "quant.act", "quant.weight", "int8.acc",
               "dequant")
-#: frames served, and weight tensors quantised, summed over the recording
-COUNTER_NAMES = ("execute.frames", "quant.weight.tensors")
+#: frames served, executor programs traced, and weight tensors quantised
+#: (per trace, inside a jitted program), summed over the recording
+COUNTER_NAMES = ("execute.frames", "execute.traces", "quant.weight.tensors")
 
 _NULL = contextlib.nullcontext()
 _NAN = float("nan")

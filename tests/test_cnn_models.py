@@ -97,6 +97,120 @@ class TestExecutorParity:
             (1, 8, 8, 144), (1, 4, 4, 144), (1, 2, 2, 144)]
 
 
+@pytest.fixture(scope="module")
+def resnet8_int8():
+    cfg = resnet.RESNET8
+    params = resnet.init(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32, 3))
+    return cfg, params, x, quant.calibrate_resnet(params, x, cfg)
+
+
+def _traced(fn):
+    """``fn()``'s result and the ``execute`` spans and programs traced."""
+    from repro import obs
+    with obs.recording() as rec:
+        out = fn()
+    calls = int((rec.rows()["name"] == obs.SPAN_NAMES.index("execute")).sum())
+    return np.asarray(out), calls, rec.counters["execute.traces"]
+
+
+class TestCompiledExecutor:
+    """One jitted program per graph, mode and set of scaled nodes."""
+
+    def test_second_call_traces_nothing(self, resnet8_int8):
+        cfg, params, x, scales = resnet8_int8
+        g = graphs.build_resnet_graph(cfg)
+        run = lambda: executor.execute(g, params, x, mode="int8",  # noqa: E731
+                                       act_scales=scales)
+        first, calls, traces = _traced(run)
+        assert (calls, traces) == (1, 1)
+        again, calls, traces = _traced(run)
+        assert (calls, traces) == (1, 0)
+        np.testing.assert_array_equal(again, first)
+
+    def test_new_batch_size_traces_once(self, resnet8_int8):
+        cfg, params, x, scales = resnet8_int8
+        g = graphs.build_resnet_graph(cfg)
+        executor.execute(g, params, x, mode="int8", act_scales=scales)
+        x3 = jax.random.normal(jax.random.PRNGKey(2), (3, 32, 32, 3))
+        run = lambda: executor.execute(g, params, x3, mode="int8",  # noqa: E731
+                                       act_scales=scales)
+        out, _, traces = _traced(run)
+        assert out.shape == (3, 10) and traces == 1
+        assert _traced(run)[2] == 0
+        # host numpy frames of a warm shape reuse the program
+        assert _traced(lambda: executor.execute(
+            g, params, np.asarray(x3), mode="int8", act_scales=scales))[2] == 0
+
+    @pytest.mark.parametrize("mutation", ["node", "edge"])
+    def test_graph_mutation_traces_again(self, resnet8_int8, mutation):
+        cfg, params, x, scales = resnet8_int8
+        g = graphs.build_resnet_graph(cfg)
+        run = lambda: executor.execute(g, params, x, mode="int8",  # noqa: E731
+                                       act_scales=scales)
+        before, _, _ = _traced(run)
+        sink = g.topo_order()[-1]
+        if mutation == "node":
+            g.add("out2", OpKind.OUTPUT, deps=[sink])   # a new sink, same logits
+        else:
+            g.add_edge(g.predecessors(sink)[0], sink)   # present: invalidates only
+        after, _, traces = _traced(run)
+        assert traces == 1
+        np.testing.assert_array_equal(after, before)
+
+    def test_changed_scale_values_give_their_own_logits(self, resnet8_int8):
+        cfg, params, x, scales = resnet8_int8
+        g = graphs.build_resnet_graph(cfg)
+        wider = {k: 1.5 * v for k, v in scales.items()}
+
+        def run(graph, s):
+            return _traced(lambda: executor.execute(graph, params, x, mode="int8",
+                                                    act_scales=s))
+
+        base, _, _ = run(g, scales)
+        changed, _, traces = run(g, wider)
+        assert traces == 0                      # same names: same program
+        fresh, _, _ = run(graphs.build_resnet_graph(cfg), wider)
+        np.testing.assert_array_equal(changed, fresh)
+        assert not np.array_equal(changed, base)
+        np.testing.assert_array_equal(run(g, scales)[0], base)
+
+    @pytest.mark.parametrize("calibrated", [True, False],
+                             ids=["act_scales", "scales_from_x"])
+    def test_int8_matches_the_op_by_op_walk(self, resnet8_int8, calibrated):
+        cfg, params, x, scales = resnet8_int8
+        g = graphs.build_resnet_graph(cfg)
+        s = scales if calibrated else None
+        compiled = np.asarray(executor.execute(g, params, x, mode="int8",
+                                               act_scales=s))
+        with jax.disable_jit():
+            eager = np.asarray(executor.execute(g, params, x, mode="int8",
+                                                act_scales=s))
+        rel = np.linalg.norm(compiled - eager) / np.linalg.norm(eager)
+        assert rel <= 1e-5
+
+    def test_inside_an_outer_jit_twice_leaks_no_tracer(self, resnet8_int8):
+        cfg, params, x, scales = resnet8_int8
+        g = graphs.build_resnet_graph(cfg)      # the scale memo is made inside
+        fn = jax.jit(lambda p, x: executor.execute(g, p, x, mode="int8",
+                                                   act_scales=scales))
+        a = np.asarray(fn(params, x))
+        b = np.asarray(fn(params, x[:1]))       # a second trace of the outer jit
+        eager = np.asarray(executor.execute(g, params, x, mode="int8",
+                                            act_scales=scales))
+        np.testing.assert_allclose(a, eager, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(b, eager[:1], rtol=1e-5, atol=1e-5)
+
+    def test_node_names_reach_the_program_metadata(self, resnet8_int8):
+        cfg, params, x, _ = resnet8_int8
+        g = graphs.build_resnet_graph(cfg)
+        hlo = jax.jit(lambda p, x: executor.execute(g, p, x, mode="int8")) \
+            .lower(params, x).compile().as_text()
+        for n in g.topo_order():
+            if g.nodes[n].kind in (OpKind.CONV, OpKind.MVM):
+                assert f"/{g.nodes[n].name}/" in hlo, g.nodes[n].name
+
+
 class TestQuant:
     @given(st.integers(0, 1000), st.integers(1, 6), st.integers(1, 64))
     @settings(max_examples=30, deadline=None)

@@ -1,5 +1,6 @@
 """Program spans and counters (``repro.obs``): off by default, and when on,
-the span tree of one int8 ``execute`` of ResNet-8."""
+the span tree of one int8 ``execute`` of ResNet-8: the graph walk's spans
+in the call that traces the compiled program, the call's span alone after."""
 
 import gc
 
@@ -24,8 +25,9 @@ def resnet8():
     scales = quant.calibrate_resnet(params, x, cfg)
     g = graphs.build_resnet_graph(cfg)
 
-    def run():
-        return executor.execute(g, params, x, mode="int8", act_scales=scales)
+    def run(graph=g):
+        return executor.execute(graph, params, x, mode="int8",
+                                act_scales=scales)
 
     return g, run
 
@@ -33,8 +35,7 @@ def resnet8():
 @pytest.fixture(scope="module")
 def recorded(resnet8):
     g, run = resnet8
-    run()                                   # compiled before recording
-    with obs.recording() as rec:
+    with obs.recording() as rec:            # the first call on g: the trace
         out = run()
     return g, rec, rec.rows(), out
 
@@ -120,6 +121,17 @@ def test_int8_execute_records_one_call(recorded):
     assert r["batch"][0] == 2 and rec.label(r["kind"][0]) == "int8"
     assert np.all(r["call"] == 0)
     assert rec.counters["execute.frames"] == 2
+    assert rec.counters["execute.traces"] == 1
+
+
+def test_warm_call_records_its_execute_span_alone(resnet8, recorded):
+    _, run = resnet8
+    with obs.recording() as rec:
+        run()
+    r = rec.rows()
+    assert [obs.SPAN_NAMES[k] for k in r["name"]] == ["execute"]
+    assert rec.counters == {"execute.frames": 2, "execute.traces": 0,
+                            "quant.weight.tensors": 0}
 
 
 def test_one_node_span_per_graph_node_in_topological_order(recorded):
@@ -147,18 +159,23 @@ def test_four_phases_under_each_conv_and_dense_node(recorded):
 
 
 def test_weight_tensors_counted_once_per_call(resnet8, recorded):
+    # once per call that traces the program: warm calls quantise no tensor
+    # on the host, their re-quantisation runs inside the compiled program
     _, rec, _, _ = recorded
     assert rec.counters["quant.weight.tensors"] == 10
     _, run = resnet8
     with obs.recording() as rec3:
         for _ in range(3):
             run()
-    assert rec3.counters["quant.weight.tensors"] == 30
+    assert rec3.counters["quant.weight.tensors"] == 0
+    assert rec3.counters["execute.traces"] == 0
     assert rec3.counters["execute.frames"] == 6
     assert (rec3.rows()["name"] == NAME["execute"]).sum() == 3
 
 
 def test_logits_bit_identical_with_recording_on_and_off(resnet8, recorded):
     _, run = resnet8
-    off = np.asarray(run())
+    # traced with recording off, on a graph of its own
+    off = np.asarray(run(graphs.build_resnet_graph(resnet.RESNET8)))
     np.testing.assert_array_equal(np.asarray(recorded[3]), off)
+    np.testing.assert_array_equal(np.asarray(run()), off)
