@@ -1,9 +1,10 @@
 """One run of one cell: set-up, the measured window, metrics, comparison.
 
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``.  Everything that
-belongs to it is found by name: its configuration in
-``bench/configs/<config>.json`` (whose ``model`` names the module under
-``bench/models`` that makes its weights and reference and deploys it), its
+belongs to it is found by name: its configuration in the ``file`` that
+``BENCHMARK.json`` gives it (whose ``model`` names the module under
+``bench/models`` that makes its weights and reference and deploys it, and
+whose ``check`` the cell is judged by, ``bench/check.py``), its
 traffic mix in ``bench/traffic/<traffic>.json`` (whose ``kind`` names the
 loop in ``bench/kinds/<kind>.py`` that drives the window), and each of its metrics
 in ``bench/metrics/<name>.py``, or ``<first part of the name>.py`` where
@@ -56,8 +57,13 @@ class Cell:
             raise KeyError(f"no workload {name!r} in BENCHMARK.json ({known})")
         entry = entries[0]
         self.name, self.chips = name, entry["chips"]
-        self.cfg = json.loads(
-            (HERE / "configs" / f"{entry['config']}.json").read_text())
+        configs = {c["name"]: c for c in bench["configs"]}
+        path = root / configs[entry["config"]]["file"]
+        self.cfg = json.loads(path.read_text())
+        try:
+            check.validate(self.cfg["check"])
+        except (KeyError, ValueError) as e:
+            raise ValueError(f"{path}: {e}") from None
         self.mix = traffic.load(HERE / "traffic" / f"{entry['traffic']}.json")
         self.kind = traffic.kind(self.mix["kind"])
         self.model = importlib.import_module(f"bench.models.{self.cfg['model']}")
@@ -195,18 +201,20 @@ def watch() -> Watch:
 # ---------------------------------------------------------------------------
 
 def answered(dep: Deployment, rec: loops.Record):
-    """Pool rows and logits of the frames answered in the window (for a
-    stream: all of its frames that were answered)."""
-    rows, logits = [], []
+    """Pool rows of the frames answered in the window (for a stream: all of
+    its frames that were answered), and where each one's answer lies in
+    ``rec.logits``: its call's index and its offset in that call."""
+    rows, where = [], []
     in_window = not dep.cell.kind.ANSWERS_AFTER_CLOSE_COUNT
     for call in rec.calls:
         if not call.get("ok") or (in_window and call["done"] > rec.end):
             continue
-        rows.append(np.arange(call["first"], call["first"] + call["n"]))
-        logits.append(rec.logits[call["index"]])
+        offsets = np.arange(call["n"])
+        rows.append(call["first"] + offsets)
+        where.append(np.stack([np.full(call["n"], call["index"]), offsets], 1))
     if not rows:
-        return np.zeros(0, int), np.zeros((0, 0), np.float32)
-    return np.concatenate(rows), np.concatenate(logits)
+        return np.zeros(0, int), np.zeros((0, 2), int)
+    return np.concatenate(rows), np.concatenate(where)
 
 
 def sample(dep: Deployment, rows: np.ndarray):
@@ -216,24 +224,32 @@ def sample(dep: Deployment, rows: np.ndarray):
     return np.sort(dep.sample_rng.choice(len(rows), size=k, replace=False))
 
 
+def gather(rec: loops.Record, where: np.ndarray) -> np.ndarray:
+    """The answers at ``where`` (rows of call index and offset), copied out
+    of ``rec.logits`` one by one: no copy of the window's other answers."""
+    return np.stack([rec.logits[call][offset] for call, offset in where])
+
+
 def compare(dep: Deployment, rec: loops.Record, control: bool = False) -> Dict:
     """The numbers compared, against the reference, for the sampled
     answers; with ``control`` also the control's numbers on the same
     frames."""
     cell = dep.cell
-    rows, logits = answered(dep, rec)
+    parts = cell.cfg["check"].get("parts")
+    rows, where = answered(dep, rec)
     if not len(rows):
         return {"numbers": {}, "frames": 0}
     pick = sample(dep, rows)
     frames = dep.pool[rows[pick]]
     ref = cell.model.reference_fn(cell.cfg)
     want = check.in_blocks(lambda x: ref(dep.params, x), frames)
-    out = {"numbers": check.numbers(logits[pick], want), "frames": len(pick)}
+    out = {"numbers": check.numbers(gather(rec, where[pick]), want, parts),
+           "frames": len(pick)}
     if control:
         qmax = cell.cfg["check"]["control_qmax"]
         ctl = cell.model.control_fn(cell.cfg, qmax)
         got = check.in_blocks(lambda x: ctl(dep.params, dep.calib, x), frames)
-        out["control"] = check.numbers(got, want)
+        out["control"] = check.numbers(got, want, parts)
     return out
 
 

@@ -168,8 +168,10 @@ def control_fn(cfg: Dict, qmax: int) -> Callable:
 def deploy(params: Dict, cfg: Dict, calib: jnp.ndarray, phases: Dict) -> Callable:
     """Run the program's deployment flow; returns ``serve(frames) -> logits``.
 
-    ``serve`` calls ``executor.execute`` eagerly, exactly as the program's
-    example does.  ``phases`` receives the seconds of each step.
+    ``serve`` calls ``executor.execute`` as the program's example does.  The
+    executor compiles the graph into one program at the first call of each
+    batch shape (set-up's warm-up) and launches it once a call after that.
+    ``phases`` receives the seconds of each step.
     """
     from repro.core import CostModel, get_scheduler, make_pus
     from repro.models import quant

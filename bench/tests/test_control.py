@@ -51,3 +51,17 @@ def test_control_at_the_program_precision_passes():
     int8 = np.asarray(cell.model.control_fn(cfg, 127)(params, calib, x))
     assert check.verdict(check.numbers(int8, want),
                          cfg["check"]["limits"])["correct"]
+
+
+def test_control_reads_each_declared_part(tmp_path):
+    # bench/control.py reports harness.compare's numbers: with parts
+    # declared, the program's and the control's readings of each part
+    from conftest import PART_LIMITS, parts_root, small_cell
+    cell = small_cell("resnet8.offline", root=parts_root(tmp_path))
+    dep = harness.Deployment(cell, 7)
+    rec = harness.window(dep, 1.0)
+    dep.serve = None
+    cmp = harness.compare(dep, rec, control=True)
+    assert list(cmp["numbers"]) == list(cmp["control"]) == list(PART_LIMITS)
+    assert check.verdict(cmp["numbers"], PART_LIMITS)["correct"]
+    assert not check.verdict(cmp["control"], PART_LIMITS)["correct"]

@@ -7,7 +7,7 @@ that each answer is broken where it is produced.
 
 import jax.numpy as jnp
 import pytest
-from conftest import cpu_run, small_cell
+from conftest import PARTS, cpu_run, parts_root, small_cell
 
 from bench.models import resnet
 
@@ -94,6 +94,19 @@ def test_fault_is_not_correct(monkeypatch, cell_name, fault):
     monkeypatch.setattr(harness, "window", window)
     res = cpu_run(cell)
     assert res["correct"] is False, res["check"]
+
+
+def dead_last_part(y):         # the last declared part returns a constant
+    return y.at[..., PARTS["last"][0]:PARTS["last"][1]].set(0.0)
+
+
+def test_fault_in_one_part_is_not_correct(monkeypatch, tmp_path):
+    broken(monkeypatch, dead_last_part)["on"] = True
+    cell = small_cell("resnet8.offline", root=parts_root(tmp_path))
+    res = cpu_run(cell)
+    assert res["correct"] is False, res["check"]
+    assert res["check"]["head.rel_l2"]["value"] <= 0.8
+    assert res["check"]["last.rel_l2"]["value"] > 0.8
 
 
 def test_unbroken_control_run_is_correct(monkeypatch):

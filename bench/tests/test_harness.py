@@ -6,11 +6,13 @@ import re
 import subprocess
 import sys
 from pathlib import Path
+from types import SimpleNamespace
 
+import numpy as np
 import pytest
-from conftest import cpu_run, small_cell
+from conftest import PART_LIMITS, cpu_run, parts_root, small_cell
 
-from bench import harness
+from bench import check, harness, loops
 
 ROOT = Path(__file__).resolve().parents[2]
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -92,6 +94,105 @@ def test_cpu_run_is_correct(name):
     assert res["check"]["failed"] == {"value": 0, "limit": 0}
     for v in res["metrics"].values():
         assert v["value"] > 0
+
+
+def test_cpu_run_judges_each_declared_part(tmp_path):
+    res = cpu_run(small_cell("resnet8.offline", root=parts_root(tmp_path)))
+    assert res["correct"] is True, res["check"]
+    assert list(res["check"]) == list(PART_LIMITS) + ["failed",
+                                                      "lowered_in_window"]
+    for name, limit in PART_LIMITS.items():
+        assert res["check"][name]["limit"] == limit
+        assert 0 < res["check"][name]["value"] <= limit
+
+
+@pytest.mark.parametrize("limits", [
+    {"rel_l2": 0.8, "worst_frame": 1.4},               # limits of no part
+    dict(list(PART_LIMITS.items())[:3]),               # a part without one
+    dict(PART_LIMITS, **{"other.rel_l2": 0.8}),        # a limit of no part
+])
+def test_cell_whose_limits_miss_its_parts_is_refused(tmp_path, limits):
+    root = parts_root(tmp_path, limits=limits)
+    with pytest.raises(ValueError, match="check.limits"):
+        harness.Cell("resnet8.offline", root)
+
+
+class RecordingModel:
+    """A reference that answers from the frames it is given, and keeps
+    them."""
+
+    def __init__(self):
+        self.seen = []
+
+    def reference_fn(self, cfg):
+        def ref(params, x):
+            self.seen.append(np.array(x))
+            return x.reshape(len(x), -1)[:, :10] * 2.0 + params
+        return ref
+
+
+def synthetic(after_close_counts, seed=3):
+    """A deployment and a window of 14 calls of 1 to 5 frames over a pool
+    of 64, with calls that failed and calls answered after the close."""
+    rng = np.random.default_rng(11)
+    kind = SimpleNamespace(ANSWERS_AFTER_CLOSE_COUNT=after_close_counts)
+    cell = SimpleNamespace(cfg={"check": {}}, kind=kind, model=RecordingModel(),
+                           mix={"sample_frames": 25})
+    dep = SimpleNamespace(cell=cell, params=0.5,
+                          pool=rng.standard_normal((64, 2, 2, 3)),
+                          sample_rng=np.random.default_rng(seed))
+    rec = loops.Record(1.0)
+    rec.t0, rec.end = 0.0, 10.0
+    for i in range(14):
+        n = 1 + i % 5
+        call = {"index": i, "first": (7 * i) % (64 - n), "n": n,
+                "done": 12.0 if i in (4, 9) else 1.0}
+        rec.calls.append(call)
+        if i in (2, 11):
+            call["ok"] = False
+            continue
+        rec.logits[i] = rng.standard_normal((n, 10)).astype(np.float32)
+        call["ok"] = True
+    return dep, rec
+
+
+def concatenating_compare(dep, rec):
+    """The comparison as it was before the sampled answers were gathered:
+    every answer concatenated, then the sample taken."""
+    rows, logits = [], []
+    in_window = not dep.cell.kind.ANSWERS_AFTER_CLOSE_COUNT
+    for call in rec.calls:
+        if not call.get("ok") or (in_window and call["done"] > rec.end):
+            continue
+        rows.append(np.arange(call["first"], call["first"] + call["n"]))
+        logits.append(rec.logits[call["index"]])
+    rows, logits = np.concatenate(rows), np.concatenate(logits)
+    pick = harness.sample(dep, rows)
+    ref = dep.cell.model.reference_fn(dep.cell.cfg)
+    want = check.in_blocks(lambda x: ref(dep.params, x), dep.pool[rows[pick]])
+    return rows[pick], logits[pick], check.numbers(logits[pick], want)
+
+
+@pytest.mark.parametrize("after_close_counts", [False, True])
+def test_compare_gathers_the_sampled_answers(after_close_counts):
+    dep, rec = synthetic(after_close_counts)
+    old_rows, old_answers, old_numbers = concatenating_compare(dep, rec)
+    old_frames = np.concatenate(dep.cell.model.seen)[:len(old_rows)]
+
+    dep, rec = synthetic(after_close_counts)
+    rows, where = harness.answered(dep, rec)
+    pick = harness.sample(dep, rows)
+    assert np.array_equal(rows[pick], old_rows)
+    answers = harness.gather(rec, where[pick])
+    assert answers.dtype == old_answers.dtype
+    assert np.array_equal(answers, old_answers)
+
+    dep, rec = synthetic(after_close_counts)
+    out = harness.compare(dep, rec)
+    assert out["frames"] == len(old_rows)
+    assert out["numbers"] == old_numbers
+    frames = np.concatenate(dep.cell.model.seen)[:len(old_rows)]
+    assert np.array_equal(frames, old_frames)
 
 
 def test_traced_cpu_run_reads_host_metrics():

@@ -208,8 +208,10 @@ def test_profile_on_the_cpu_records_the_program_spans():
                                   pair_seconds=0.5)
     assert [p["recording"] for p in res["pairs"]] == [False, True]
     assert all(p["dispatch_ms"] > 0 for p in res["pairs"])
-    assert res["per_call"] == {"execute.frames": 4.0,
-                               "quant.weight.tensors": 10.0}
+    # a warm window runs the compiled program: nothing is traced, and the
+    # weights are re-quantised inside it, where no counter sees them
+    assert res["per_call"] == {"execute.frames": 4.0, "execute.traces": 0.0,
+                               "quant.weight.tensors": 0.0}
     assert res["execute_spans"] > 0
     share = res["host_share_untraced"]
     assert set(share) == set(SPAN_NAMES)
