@@ -13,20 +13,31 @@ is one launch, whose only host-to-device transfer is ``x`` itself: the
 activation scales reach the program as one device vector, made once per
 set of values.  Each node's operations carry its name as a
 ``jax.named_scope``, so device time can be put down to graph nodes.
+``input_magnitudes``, which ``quant.calibrate_graph`` calls, is a second
+program per graph: the float walk, returning the largest input magnitude
+of each node with learned weights.
 
 Two arithmetic modes:
 * ``mode="float"`` — float32 reference.
 * ``mode="int8"``  — per-node INT8 quantized execution (per-channel
-  weights, per-tensor activations quantized at every node boundary),
+  weights, per-tensor activations quantized at every conv input),
   matching the paper's INT8 deployment.
 
 Numerics parity with the un-scheduled reference model is asserted in
-tests (float mode: exact; int8 mode: bounded quantization error).
+tests (float mode: ``resnet.forward`` and ``yolo.forward`` within float
+rounding; int8 mode: bounded quantization error).
 
-Supported node kinds cover the ResNet graphs: CONV, MVM, ADD,
-GLOBAL_POOL, INPUT and OUTPUT.  The YOLO 233-node graph is scheduled and
-simulated, but ``execute`` raises on its other kinds; ``yolo.forward`` runs
-that model outside the graph path.
+Node kinds: CONV, MVM, ADD, GLOBAL_POOL, INPUT and OUTPUT (the ResNet
+graphs), and ACT, MUL, CONCAT, SPLIT, POOL_MAX, UPSAMPLE, RESHAPE and
+SOFTMAX (YOLOv8n; ``graphs.py`` documents their metadata).  A node reads
+its operands in the order of ``meta["inputs"]`` where the graph gives it,
+else in the order of its predecessors.  In int8 mode only the nodes with
+learned weights are quantised: each CONV's input per tensor and its
+weights per output channel, and each MVM with a ``param``.  Everything
+else runs in float32 by design, inside the same program: YOLOv8n's SiLU
+(ACT + MUL), residual adds, concats, pools, upsamples and its whole
+decode (the DFL softmax, the fixed-weight ``dfl.conv``, dist2bbox and the
+class sigmoid), none of which has learned weights.
 
 Spans (``repro.obs``, recorded only inside ``obs.recording()``): one
 ``execute`` per call (``kind`` = the mode, ``batch`` = frames).  Under it,
@@ -34,13 +45,16 @@ only in a call that traces the program, one ``node`` per graph node in
 topological order (``node`` = the graph's node name, ``kind`` = its
 ``OpKind``), and in int8 mode under each conv and dense node the phase
 spans of ``quant.quantized_conv2d`` and ``quant.quantized_matmul``: these
-time the tracing, not the device.  Counters: ``execute.frames`` (per call)
-and ``execute.traces`` (programs traced).
+time the tracing, not the device.  Counters: ``execute.frames`` (per call),
+``execute.traces`` (programs traced), and ``execute.bytes_in`` and
+``execute.bytes_out``, the bytes of ``x`` and of the result, from their
+shapes and dtypes (no wait for the device).
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+import math
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,14 +74,88 @@ def _param_at(params, path):
     return node
 
 
+def _nbytes(a) -> int:
+    return math.prod(a.shape) * a.dtype.itemsize
+
+
 def execute(g: Graph, params: Dict, x: jnp.ndarray, mode: str = "float",
             act_scales: Optional[Dict[str, float]] = None) -> jnp.ndarray:
     """Run graph ``g`` on batch ``x`` (NHWC).  Returns the sink output."""
     obs.count("execute.frames", x.shape[0])
     with obs.span("execute", kind=mode, batch=x.shape[0]):
         names = tuple(sorted(act_scales)) if act_scales else ()
-        return _program(g, mode, names)(params, x,
-                                        _scale_vector(g, act_scales, names))
+        out = _program(g, mode, names)(params, x,
+                                       _scale_vector(g, act_scales, names))
+    if obs.on():
+        obs.count("execute.bytes_in", _nbytes(x))
+        obs.count("execute.bytes_out", _nbytes(out))
+    return out
+
+
+def weighted_nodes(g: Graph) -> List[str]:
+    """Names of the CONV and MVM nodes with learned weights (a ``param``),
+    in topological order: the nodes an int8 program quantises."""
+    return [g.nodes[n].name for n in g.topo_order()
+            if g.nodes[n].kind in (OpKind.CONV, OpKind.MVM)
+            and g.nodes[n].meta.get("param") is not None]
+
+
+def input_magnitudes(g: Graph, params: Dict, x: jnp.ndarray) -> jnp.ndarray:
+    """The largest magnitude of the input of each of ``weighted_nodes(g)``
+    over batch ``x``, in float mode, as one vector: one compiled program
+    per graph."""
+    key = ("executor.magnitudes",)
+    program = g.scratch().get(key)
+    if program is None:
+        steps = _steps(g)
+        tapped = set(weighted_nodes(g))
+
+        def magnitudes(params, x):
+            taps = []
+            _walk(steps, params, x, "float", None, {}, tapped, taps)
+            return jnp.stack(taps)
+
+        program = g.scratch()[key] = jax.jit(magnitudes)
+    return program(params, x)
+
+
+def _steps(g: Graph):
+    """Each node in topological order with its operands as (node id,
+    part) pairs: ``meta["inputs"]`` where the node lists them (``part``
+    picks a SPLIT's output), else its predecessors (``part`` None)."""
+    ids = {node.name: nid for nid, node in g.nodes.items()}
+    steps = []
+    for nid in g.topo_order():
+        node, preds = g.nodes[nid], g.predecessors(nid)
+        refs = node.meta.get("inputs")
+        if refs is None:
+            steps.append((node, [(p, None) for p in preds]))
+            continue
+        ops = [(ids[name], part) for name, part in refs]
+        if sorted({i for i, _ in ops}) != sorted(preds):
+            raise ValueError(f"node {node.name}: meta inputs {refs} do not "
+                             "name its predecessors")
+        steps.append((node, ops))
+    return steps
+
+
+def _walk(steps, params, x, mode, scales, index, tapped=(), taps=None):
+    """Evaluate ``steps``; returns the last node's output.  The largest
+    magnitude of the input of each node named in ``tapped`` is appended
+    to ``taps``."""
+    env: Dict[int, jnp.ndarray] = {}
+    out = None
+    for node, ops in steps:
+        ins = [env[n] if part is None else env[n][part] for n, part in ops]
+        i = index.get(node.name)
+        with jax.named_scope(node.name), \
+                obs.span("node", node=node.name, kind=node.kind.name):
+            if node.name in tapped:
+                taps.append(jnp.max(jnp.abs(ins[0] if ins else x)))
+            out = env[node.node_id] = _run_node(
+                node, ins, params, x, mode,
+                None if i is None else scales[i])
+    return out
 
 
 def _program(g: Graph, mode: str, names: Tuple[str, ...]):
@@ -77,22 +165,12 @@ def _program(g: Graph, mode: str, names: Tuple[str, ...]):
     program = g.scratch().get(key)
     if program is not None:
         return program
-    steps = [(g.nodes[nid], g.predecessors(nid)) for nid in g.topo_order()]
+    steps = _steps(g)
     index = {name: i for i, name in enumerate(names)}
 
     def execute_graph(params, x, scales):
         obs.count("execute.traces")
-        env: Dict[int, jnp.ndarray] = {}
-        out = None
-        for node, preds in steps:
-            ins = [env[p] for p in preds]
-            i = index.get(node.name)
-            with jax.named_scope(node.name), \
-                    obs.span("node", node=node.name, kind=node.kind.name):
-                out = env[node.node_id] = _run_node(
-                    node, ins, params, x, mode,
-                    None if i is None else scales[i])
-        return out
+        return _walk(steps, params, x, mode, scales, index)
 
     program = g.scratch()[key] = jax.jit(execute_graph)
     return program
@@ -115,23 +193,54 @@ def _scale_vector(g: Graph, act_scales, names):
 
 
 def _run_node(node, ins, params, x, mode, x_scale):
+    meta = node.meta
     if node.kind == OpKind.CONV:
         inp = ins[0] if ins else x
-        p = _param_at(params, node.meta["param"])
+        p = _param_at(params, meta["param"])
         if mode == "int8":
             y = quant.quantized_conv2d(
-                inp, p["w"], p["b"], stride=node.meta["stride"],
-                padding=node.meta["padding"], x_scale=x_scale)
-            return L.activate(y, node.meta.get("act"))
-        return L.conv2d(p, inp, stride=node.meta["stride"],
-                        padding=node.meta["padding"], act=node.meta.get("act"))
+                inp, p["w"], p["b"], stride=meta["stride"],
+                padding=meta["padding"], x_scale=x_scale)
+            return L.activate(y, meta.get("act"))
+        return L.conv2d(p, inp, stride=meta["stride"],
+                        padding=meta["padding"], act=meta.get("act"))
     if node.kind == OpKind.MVM:
-        p = _param_at(params, node.meta["param"])
+        if meta.get("param") is None:       # fixed weights, float32 in any mode
+            return jnp.matmul(ins[0], jnp.asarray(meta["weights"], jnp.float32),
+                              precision=jax.lax.Precision.HIGHEST)
+        p = _param_at(params, meta["param"])
         if mode == "int8":
             return quant.quantized_matmul(ins[0], p["w"], p["b"])
         return L.dense(p, ins[0])
     if node.kind == OpKind.ADD:
-        return L.activate(ins[0] + ins[1], node.meta.get("act"))
+        # ``const`` (if any) plus each operand times its sign, then ``act``
+        out = np.asarray(meta["const"], np.float32) if "const" in meta else None
+        for sign, t in zip(meta.get("signs", [1] * len(ins)), ins):
+            if out is None:
+                out = t if sign > 0 else -t
+            else:
+                out = out + t if sign > 0 else out - t
+        return L.activate(out, meta.get("act"))
+    if node.kind == OpKind.MUL:
+        if "const" in meta:
+            return ins[0] * np.asarray(meta["const"], np.float32)
+        return ins[0] * ins[1]
+    if node.kind == OpKind.ACT:
+        return L.activate(ins[0], meta["act"])
+    if node.kind == OpKind.CONCAT:
+        return jnp.concatenate(ins, axis=meta["axis"])
+    if node.kind == OpKind.SPLIT:
+        return tuple(jax.lax.slice_in_dim(ins[0], a, b, axis=meta["axis"])
+                     for a, b in meta["sections"])
+    if node.kind == OpKind.POOL_MAX:
+        return L.max_pool(ins[0], meta["size"], stride=meta["stride"],
+                          padding=meta["padding"])
+    if node.kind == OpKind.UPSAMPLE:
+        return L.upsample_nearest(ins[0], meta["factor"])
+    if node.kind == OpKind.RESHAPE:
+        return ins[0].reshape((ins[0].shape[0], *meta["shape"]))
+    if node.kind == OpKind.SOFTMAX:
+        return L.softmax(ins[0], axis=meta["axis"])
     if node.kind == OpKind.GLOBAL_POOL:
         return L.global_avg_pool(ins[0])
     if node.kind == OpKind.INPUT:
@@ -140,4 +249,4 @@ def _run_node(node, ins, params, x, mode, x_scale):
         return ins[0]
     raise NotImplementedError(
         f"executor does not implement {node.kind} (node {node.name}); "
-        "ResNet-family graphs only — see module docstring")
+        "the kinds it runs are listed in the module docstring")

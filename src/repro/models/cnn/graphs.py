@@ -5,9 +5,12 @@ emits a ``repro.core.Graph`` whose nodes carry:
 
 * scheduling cost metadata (flops, weight_bytes, out_bytes/elems, IMC
   tiling meta) consumed by ``repro.core.cost.CostModel``;
-* execution metadata (``meta["param"]`` path into the model's parameter
-  pytree + op attributes) consumed by ``repro.models.cnn.executor`` so a
-  scheduled graph remains a *runnable program*, not just a cost table.
+* execution metadata consumed by ``repro.models.cnn.executor``, so that a
+  scheduled graph remains a *runnable program*, not just a cost table:
+  each conv and dense node's ``meta["param"]`` path into the model's
+  parameter pytree and its op attributes, and in the YOLOv8n graph every
+  node's ordered operands (``meta["inputs"]``) and the attributes of its
+  other kinds (see the YOLOv8n section below).
 
 Node numbering is topological and matches the paper's Table I ids for
 ResNet18-CIFAR (verified in tests/test_cnn_graphs.py).
@@ -15,21 +18,24 @@ ResNet18-CIFAR (verified in tests/test_cnn_graphs.py).
 
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import List, Optional, Tuple, Union
 
 from repro.core.graph import Graph, OpKind
 
 from . import layers as L
 from .resnet import RESNET8, RESNET18_CIFAR
-from .yolo import CH, NC, REG_MAX, YOLOV8N
+from .yolo import CH, NC, REG_MAX, STRIDES, YOLOV8N, autopad
 
 
 def _add_conv(g: Graph, name: str, deps: List[int], h: int, w: int, k: int,
               cin: int, cout: int, stride: int, act: Optional[str],
-              param: tuple, padding: str = "SAME") -> Tuple[int, int, int]:
+              param: tuple, padding="SAME",
+              inputs: Optional[list] = None) -> Tuple[int, int, int]:
     cost = L.conv_cost(h, w, k, cin, cout, stride, padding)
     meta = dict(cost.pop("meta"))
     meta.update(param=param, stride=stride, act=act, padding=padding, k=k)
+    if inputs is not None:
+        meta["inputs"] = inputs
     n = g.add(name, OpKind.CONV, deps=deps, fused_act=act, meta=meta, **cost)
     ho, wo = meta["out_hw"]
     return n.node_id, ho, wo
@@ -110,157 +116,237 @@ TABLE1_IMC_NODE_IDS = frozenset(
 # modelled as an MVM node (the paper counts 63 *convolutional* nodes,
 # excluding it).  The three detection scales are the paper's "3 parallel
 # main branches".
+#
+# Execution metadata.  Each node lists its operands in order as
+# ``meta["inputs"]``, pairs of (producer's name, part): a SPLIT yields a
+# tuple of slices and ``part`` picks one, for any other producer it is
+# None.  The graph keeps one edge per producer, so which half of a C2f
+# split its concat and its first bottleneck take is told only here.
+# Layout is NHWC, and (batch, anchors, ...) after the head's reshapes;
+# every ``axis`` counts the batch.  Per kind:
+#
+#   CONV      param (path into ``yolo.init``'s pytree), stride, padding
+#             (explicit, ``yolo.autopad``: k // 2 on every side)
+#   ACT       act ("sigmoid")
+#   MUL       the product of two operands, or of one and ``const``
+#   ADD       ``const`` (if any) plus each operand times its ``signs``
+#             (default all +1, as the residual adds)
+#   SPLIT     axis, sections ([start, stop) of each slice)
+#   CONCAT    axis
+#   POOL_MAX  size, stride, padding
+#   UPSAMPLE  factor (nearest neighbour)
+#   RESHAPE   shape (without the batch)
+#   SOFTMAX   axis
+#   MVM       weights: dfl.conv's fixed bins 0..15, a constant (no param)
+#
+# ``const`` is a number, or nested lists that broadcast against the
+# operands: the decode's anchor centres (anchors, 2) and strides
+# (anchors, 1), computed here.
 # ===========================================================================
 
+Ref = Union[int, Tuple[int, int]]       # node id, or (SPLIT id, part)
 
 
 class _Emit:
-    """Stateful helper emitting ONNX-level nodes with cost metadata."""
+    """Stateful helper emitting ONNX-level nodes with cost and execution
+    metadata; operands are ``Ref``s."""
 
     def __init__(self, g: Graph):
         self.g = g
 
-    def conv_module(self, name, dep, h, w, k, cin, cout, stride=1):
+    def inputs(self, refs: List[Ref]) -> Tuple[List[int], list]:
+        """The deps (one per producer, in order) and ``meta["inputs"]``."""
+        pairs = [r if isinstance(r, tuple) else (r, None) for r in refs]
+        return ([nid for nid, _ in pairs],
+                [(self.g.nodes[nid].name, part) for nid, part in pairs])
+
+    def conv(self, name, path, src: Optional[Ref], h, w, k, cin, cout,
+             stride=1):
+        """A plain conv reading ``src`` (the frames where None)."""
+        deps, inputs = self.inputs([] if src is None else [src])
+        pad = [list(side) for side in autopad(k)]
+        return _add_conv(self.g, name, deps, h, w, k, cin, cout, stride,
+                         None, param=path, padding=pad, inputs=inputs)
+
+    def conv_module(self, name, path, src, h, w, k, cin, cout, stride=1):
         """Conv + Sigmoid + Mul (SiLU) -> returns (mul_id, ho, wo)."""
-        cid, ho, wo = _add_conv(self.g, f"{name}.conv", [dep] if dep else [],
-                                h, w, k, cin, cout, stride, None,
-                                param=(name,))
+        cid, ho, wo = self.conv(f"{name}.conv", path, src, h, w, k, cin,
+                                cout, stride)
         n_el = ho * wo * cout
-        sig = self._elem(f"{name}.sigmoid", OpKind.ACT, [cid], n_el)
-        mul = self._elem(f"{name}.mul", OpKind.MUL, [cid, sig], n_el)
+        sig = self.node(f"{name}.sigmoid", OpKind.ACT, [cid], n_el,
+                        act="sigmoid")
+        mul = self.node(f"{name}.mul", OpKind.MUL, [cid, sig], n_el)
         return mul, ho, wo
 
-    def plain_conv(self, name, dep, h, w, k, cin, cout, stride=1):
-        cid, ho, wo = _add_conv(self.g, name, [dep], h, w, k, cin, cout,
-                                stride, None, param=(name,))
-        return cid, ho, wo
-
-    def _elem(self, name, kind, deps, n_elems):
+    def node(self, name, kind, refs: List[Ref], n_elems, **meta):
         cost = L.elem_cost(n_elems)
         cost.pop("meta")
-        return self.g.add(name, kind, deps=deps, meta={}, **cost).node_id
+        deps, meta["inputs"] = self.inputs(refs)
+        return self.g.add(name, kind, deps=deps, meta=meta, **cost).node_id
 
-    def elem(self, name, kind, deps, n_elems):
-        return self._elem(name, kind, deps, n_elems)
-
-    def c2f(self, name, dep, h, w, cin, cout, n, shortcut):
+    def c2f(self, name, src, h, w, cin, cout, n, shortcut):
         c = cout // 2
-        cv1, h, w = self.conv_module(f"{name}.cv1", dep, h, w, 1, cin, cout)
-        split = self._elem(f"{name}.split", OpKind.SPLIT, [cv1], h * w * cout)
-        chunks = [split, split]
-        prev = split
+        cv1, h, w = self.conv_module(f"{name}.cv1", (name, "cv1"), src, h, w,
+                                     1, cin, cout)
+        split = self.node(f"{name}.split", OpKind.SPLIT, [cv1], h * w * cout,
+                          axis=-1, sections=[[0, c], [c, cout]])
+        chunks: List[Ref] = [(split, 0), (split, 1)]
+        prev: Ref = (split, 1)
         for i in range(n):
-            m1, _, _ = self.conv_module(f"{name}.m{i}.cv1", prev, h, w, 3, c, c)
-            m2, _, _ = self.conv_module(f"{name}.m{i}.cv2", m1, h, w, 3, c, c)
+            m1, _, _ = self.conv_module(f"{name}.m{i}.cv1",
+                                        (name, "m", i, "cv1"), prev, h, w,
+                                        3, c, c)
+            m2, _, _ = self.conv_module(f"{name}.m{i}.cv2",
+                                        (name, "m", i, "cv2"), m1, h, w,
+                                        3, c, c)
             if shortcut:
-                prev = self._elem(f"{name}.m{i}.add", OpKind.ADD,
-                                  [prev, m2], h * w * c)
+                prev = self.node(f"{name}.m{i}.add", OpKind.ADD, [prev, m2],
+                                 h * w * c)
             else:
                 prev = m2
             chunks.append(prev)
-        cat = self._elem(f"{name}.concat", OpKind.CONCAT, chunks,
-                         h * w * (2 + n) * c)
-        cv2, h, w = self.conv_module(f"{name}.cv2", cat, h, w, 1,
-                                     (2 + n) * c, cout)
-        return cv2, h, w
+        cat = self.node(f"{name}.concat", OpKind.CONCAT, chunks,
+                        h * w * (2 + n) * c, axis=-1)
+        return self.conv_module(f"{name}.cv2", (name, "cv2"), cat, h, w, 1,
+                                (2 + n) * c, cout)
 
-    def sppf(self, name, dep, h, w, c):
-        cv1, h, w = self.conv_module(f"{name}.cv1", dep, h, w, 1, c, c // 2)
+    def sppf(self, name, src, h, w, c):
+        cv1, h, w = self.conv_module(f"{name}.cv1", (name, "cv1"), src, h, w,
+                                     1, c, c // 2)
         n_el = h * w * (c // 2)
-        p1 = self._elem(f"{name}.pool1", OpKind.POOL_MAX, [cv1], n_el)
-        p2 = self._elem(f"{name}.pool2", OpKind.POOL_MAX, [p1], n_el)
-        p3 = self._elem(f"{name}.pool3", OpKind.POOL_MAX, [p2], n_el)
-        cat = self._elem(f"{name}.concat", OpKind.CONCAT, [cv1, p1, p2, p3],
-                         h * w * 2 * c)
-        cv2, h, w = self.conv_module(f"{name}.cv2", cat, h, w, 1, 2 * c, c)
-        return cv2, h, w
+        pool = dict(size=5, stride=1, padding="SAME")
+        p1 = self.node(f"{name}.pool1", OpKind.POOL_MAX, [cv1], n_el, **pool)
+        p2 = self.node(f"{name}.pool2", OpKind.POOL_MAX, [p1], n_el, **pool)
+        p3 = self.node(f"{name}.pool3", OpKind.POOL_MAX, [p2], n_el, **pool)
+        cat = self.node(f"{name}.concat", OpKind.CONCAT, [cv1, p1, p2, p3],
+                        h * w * 2 * c, axis=-1)
+        return self.conv_module(f"{name}.cv2", (name, "cv2"), cat, h, w, 1,
+                                2 * c, c)
 
 
 def build_yolov8n_graph(cfg: dict = YOLOV8N) -> Graph:
+    """The deployment DAG of YOLOv8n for frames of ``cfg["image_hw"]``,
+    each side a multiple of the largest stride, 32."""
+    h, w = cfg["image_hw"]
+    if h % STRIDES[-1] or w % STRIDES[-1]:
+        raise ValueError(f"image_hw {cfg['image_hw']} is not a multiple of "
+                         f"{STRIDES[-1]}")
     g = Graph(cfg["name"])
     e = _Emit(g)
-    h, w = cfg["image_hw"]
 
     # ---- backbone -------------------------------------------------------
-    b0, h, w = e.conv_module("b0", None, h, w, 3, 3, CH["p1"], 2)
-    b1, h, w = e.conv_module("b1", b0, h, w, 3, CH["p1"], CH["p2"], 2)
+    b0, h, w = e.conv_module("b0", ("b0",), None, h, w, 3, 3, CH["p1"], 2)
+    b1, h, w = e.conv_module("b1", ("b1",), b0, h, w, 3, CH["p1"], CH["p2"], 2)
     b2, h, w = e.c2f("b2", b1, h, w, CH["p2"], CH["p2"], 1, True)
-    b3, h, w = e.conv_module("b3", b2, h, w, 3, CH["p2"], CH["p3"], 2)
+    b3, h, w = e.conv_module("b3", ("b3",), b2, h, w, 3, CH["p2"], CH["p3"], 2)
     p3, h3, w3 = e.c2f("b4", b3, h, w, CH["p3"], CH["p3"], 2, True)
-    b5, h, w = e.conv_module("b5", p3, h3, w3, 3, CH["p3"], CH["p4"], 2)
+    b5, h, w = e.conv_module("b5", ("b5",), p3, h3, w3, 3, CH["p3"], CH["p4"],
+                             2)
     p4, h4, w4 = e.c2f("b6", b5, h, w, CH["p4"], CH["p4"], 2, True)
-    b7, h, w = e.conv_module("b7", p4, h4, w4, 3, CH["p4"], CH["p5"], 2)
+    b7, h, w = e.conv_module("b7", ("b7",), p4, h4, w4, 3, CH["p4"], CH["p5"],
+                             2)
     b8, h, w = e.c2f("b8", b7, h, w, CH["p5"], CH["p5"], 1, True)
     p5, h5, w5 = e.sppf("b9", b8, h, w, CH["p5"])
 
     # ---- neck (PAN) ------------------------------------------------------
-    u1 = e.elem("n10.upsample", OpKind.UPSAMPLE, [p5], h4 * w4 * CH["p5"])
-    c1 = e.elem("n11.concat", OpKind.CONCAT, [u1, p4],
-                h4 * w4 * (CH["p4"] + CH["p5"]))
+    u1 = e.node("n10.upsample", OpKind.UPSAMPLE, [p5], h4 * w4 * CH["p5"],
+                factor=2)
+    c1 = e.node("n11.concat", OpKind.CONCAT, [u1, p4],
+                h4 * w4 * (CH["p4"] + CH["p5"]), axis=-1)
     n12, _, _ = e.c2f("n12", c1, h4, w4, CH["p4"] + CH["p5"], CH["p4"], 1, False)
-    u2 = e.elem("n13.upsample", OpKind.UPSAMPLE, [n12], h3 * w3 * CH["p4"])
-    c2 = e.elem("n14.concat", OpKind.CONCAT, [u2, p3],
-                h3 * w3 * (CH["p3"] + CH["p4"]))
+    u2 = e.node("n13.upsample", OpKind.UPSAMPLE, [n12], h3 * w3 * CH["p4"],
+                factor=2)
+    c2 = e.node("n14.concat", OpKind.CONCAT, [u2, p3],
+                h3 * w3 * (CH["p3"] + CH["p4"]), axis=-1)
     n15, _, _ = e.c2f("n15", c2, h3, w3, CH["p3"] + CH["p4"], CH["p3"], 1, False)
-    n16, _, _ = e.conv_module("n16", n15, h3, w3, 3, CH["p3"], CH["p3"], 2)
-    c3 = e.elem("n17.concat", OpKind.CONCAT, [n16, n12],
-                h4 * w4 * (CH["p3"] + CH["p4"]))
+    n16, _, _ = e.conv_module("n16", ("n16",), n15, h3, w3, 3, CH["p3"],
+                              CH["p3"], 2)
+    c3 = e.node("n17.concat", OpKind.CONCAT, [n16, n12],
+                h4 * w4 * (CH["p3"] + CH["p4"]), axis=-1)
     n18, _, _ = e.c2f("n18", c3, h4, w4, CH["p3"] + CH["p4"], CH["p4"], 1, False)
-    n19, _, _ = e.conv_module("n19", n18, h4, w4, 3, CH["p4"], CH["p4"], 2)
-    c4 = e.elem("n20.concat", OpKind.CONCAT, [n19, p5],
-                h5 * w5 * (CH["p4"] + CH["p5"]))
+    n19, _, _ = e.conv_module("n19", ("n19",), n18, h4, w4, 3, CH["p4"],
+                              CH["p4"], 2)
+    c4 = e.node("n20.concat", OpKind.CONCAT, [n19, p5],
+                h5 * w5 * (CH["p4"] + CH["p5"]), axis=-1)
     n21, _, _ = e.c2f("n21", c4, h5, w5, CH["p4"] + CH["p5"], CH["p5"], 1, False)
 
     # ---- detect head: 3 scales, box (cv2) + cls (cv3) branches -----------
     feats = [(n15, h3, w3, CH["p3"]), (n18, h4, w4, CH["p4"]),
              (n21, h5, w5, CH["p5"])]
     c2_, c3_ = max(16, CH["p3"] // 4, 4 * REG_MAX), max(CH["p3"], min(NC, 100))
+    no = 4 * REG_MAX + NC
     scale_outs = []
     for i, (f, fh, fw, fc) in enumerate(feats):
-        bx, _, _ = e.conv_module(f"head.cv2.{i}.0", f, fh, fw, 3, fc, c2_)
-        bx, _, _ = e.conv_module(f"head.cv2.{i}.1", bx, fh, fw, 3, c2_, c2_)
-        bx, _, _ = e.plain_conv(f"head.cv2.{i}.2", bx, fh, fw, 1, c2_,
-                                4 * REG_MAX)
-        cl, _, _ = e.conv_module(f"head.cv3.{i}.0", f, fh, fw, 3, fc, c3_)
-        cl, _, _ = e.conv_module(f"head.cv3.{i}.1", cl, fh, fw, 3, c3_, c3_)
-        cl, _, _ = e.plain_conv(f"head.cv3.{i}.2", cl, fh, fw, 1, c3_, NC)
-        n_el = fh * fw * (4 * REG_MAX + NC)
-        cat = e.elem(f"head.concat.{i}", OpKind.CONCAT, [bx, cl], n_el)
-        rs = e.elem(f"head.reshape.{i}", OpKind.RESHAPE, [cat], n_el)
+        branches = []
+        for cv, width, cout in (("cv2", c2_, 4 * REG_MAX), ("cv3", c3_, NC)):
+            name = f"head.{cv}.{i}"
+            x, _, _ = e.conv_module(f"{name}.0", ("head", cv, i, "0"), f, fh,
+                                    fw, 3, fc, width)
+            x, _, _ = e.conv_module(f"{name}.1", ("head", cv, i, "1"), x, fh,
+                                    fw, 3, width, width)
+            x, _, _ = e.conv(f"{name}.2", ("head", cv, i, "2"), x, fh, fw, 1,
+                             width, cout)
+            branches.append(x)
+        n_el = fh * fw * no
+        cat = e.node(f"head.concat.{i}", OpKind.CONCAT, branches, n_el,
+                     axis=-1)
+        rs = e.node(f"head.reshape.{i}", OpKind.RESHAPE, [cat], n_el,
+                    shape=[fh * fw, no])
         scale_outs.append((rs, fh * fw))
 
     anchors = sum(a for _, a in scale_outs)          # 8400 at 640x640
-    no = 4 * REG_MAX + NC
-    zcat = e.elem("head.concat_scales", OpKind.CONCAT,
-                  [nid for nid, _ in scale_outs], anchors * no)
-    spl = e.elem("head.split_box_cls", OpKind.SPLIT, [zcat], anchors * no)
+    zcat = e.node("head.concat_scales", OpKind.CONCAT,
+                  [nid for nid, _ in scale_outs], anchors * no, axis=1)
+    spl = e.node("head.split_box_cls", OpKind.SPLIT, [zcat], anchors * no,
+                 axis=-1, sections=[[0, 4 * REG_MAX], [4 * REG_MAX, no]])
 
-    # DFL: Reshape -> Transpose -> Softmax -> Conv(1x1 fixed) -> Reshape
+    # DFL: Reshape -> Transpose -> Softmax -> Conv(1x1 fixed) -> Reshape.
+    # ONNX's Transpose brings the bins to the softmax's axis; channels-last,
+    # they are there already, so it is a reshape to the same shape here.
     dfl_el = anchors * 4 * REG_MAX
-    d1 = e.elem("dfl.reshape1", OpKind.RESHAPE, [spl], dfl_el)
-    d2 = e.elem("dfl.transpose", OpKind.RESHAPE, [d1], dfl_el)
-    d3 = e.elem("dfl.softmax", OpKind.SOFTMAX, [d2], dfl_el)
+    bins = [anchors, 4, REG_MAX]
+    d1 = e.node("dfl.reshape1", OpKind.RESHAPE, [(spl, 0)], dfl_el, shape=bins)
+    d2 = e.node("dfl.transpose", OpKind.RESHAPE, [d1], dfl_el, shape=bins)
+    d3 = e.node("dfl.softmax", OpKind.SOFTMAX, [d2], dfl_el, axis=-1)
     dfl_cost = L.dense_cost(REG_MAX, 1)
     dfl_meta = dict(dfl_cost.pop("meta"))
-    dfl_meta.update(param=None, n_vectors=anchors * 4)
+    deps, inputs = e.inputs([d3])
+    dfl_meta.update(param=None, n_vectors=anchors * 4, inputs=inputs,
+                    weights=[[float(i)] for i in range(REG_MAX)])
     dfl_cost["flops"] = 2.0 * dfl_el
     dfl_cost["out_bytes"] = dfl_cost["out_elems"] = float(anchors * 4)
-    d4 = g.add("dfl.conv", OpKind.MVM, deps=[d3], meta=dfl_meta,
+    d4 = g.add("dfl.conv", OpKind.MVM, deps=deps, meta=dfl_meta,
                **dfl_cost).node_id
-    d5 = e.elem("dfl.reshape2", OpKind.RESHAPE, [d4], anchors * 4)
+    d5 = e.node("dfl.reshape2", OpKind.RESHAPE, [d4], anchors * 4,
+                shape=[anchors, 4])
 
-    # dist2bbox: slices, subs/adds, concat, stride mul
-    lt = e.elem("box.slice_lt", OpKind.SPLIT, [d5], anchors * 2)
-    rb = e.elem("box.slice_rb", OpKind.SPLIT, [d5], anchors * 2)
-    x1y1 = e.elem("box.sub_x1y1", OpKind.ADD, [lt], anchors * 2)
-    x2y2 = e.elem("box.add_x2y2", OpKind.ADD, [rb], anchors * 2)
-    csum = e.elem("box.add_center", OpKind.ADD, [x1y1, x2y2], anchors * 2)
-    cdiv = e.elem("box.div_center", OpKind.MUL, [csum], anchors * 2)
-    wh = e.elem("box.sub_wh", OpKind.ADD, [x1y1, x2y2], anchors * 2)
-    bcat = e.elem("box.concat_xywh", OpKind.CONCAT, [cdiv, wh], anchors * 4)
-    bmul = e.elem("box.mul_strides", OpKind.MUL, [bcat], anchors * 4)
-    csig = e.elem("cls.sigmoid", OpKind.ACT, [spl], anchors * NC)
-    e.elem("out.concat", OpKind.CONCAT, [bmul, csig], anchors * (4 + NC))
+    # dist2bbox: slices, subs/adds, concat, stride mul.  Constants: each
+    # anchor's centre in grid cells, row by row of each scale, and its stride
+    centres, strides = [], []
+    for (_, fh, fw, _), s in zip(feats, STRIDES):
+        centres += [[x + 0.5, y + 0.5] for y in range(fh) for x in range(fw)]
+        strides += [[float(s)] for _ in range(fh * fw)]
+    half = anchors * 2
+    lt = e.node("box.slice_lt", OpKind.SPLIT, [d5], half, axis=-1,
+                sections=[[0, 2]])
+    rb = e.node("box.slice_rb", OpKind.SPLIT, [d5], half, axis=-1,
+                sections=[[2, 4]])
+    x1y1 = e.node("box.sub_x1y1", OpKind.ADD, [(lt, 0)], half, signs=[-1],
+                  const=centres)
+    x2y2 = e.node("box.add_x2y2", OpKind.ADD, [(rb, 0)], half, signs=[1],
+                  const=centres)
+    csum = e.node("box.add_center", OpKind.ADD, [x1y1, x2y2], half,
+                  signs=[1, 1])
+    cdiv = e.node("box.div_center", OpKind.MUL, [csum], half, const=0.5)
+    wh = e.node("box.sub_wh", OpKind.ADD, [x1y1, x2y2], half, signs=[-1, 1])
+    bcat = e.node("box.concat_xywh", OpKind.CONCAT, [cdiv, wh], anchors * 4,
+                  axis=-1)
+    bmul = e.node("box.mul_strides", OpKind.MUL, [bcat], anchors * 4,
+                  const=strides)
+    csig = e.node("cls.sigmoid", OpKind.ACT, [(spl, 1)], anchors * NC,
+                  act="sigmoid")
+    e.node("out.concat", OpKind.CONCAT, [bmul, csig], anchors * (4 + NC),
+           axis=-1)
 
     g.validate()
     return g

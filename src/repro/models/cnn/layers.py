@@ -119,15 +119,19 @@ def softmax(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
 # shape/cost bookkeeping shared with the deployment-graph builders
 # ---------------------------------------------------------------------------
 
-def conv_out_hw(h: int, w: int, k: int, stride: int, padding: str) -> Tuple[int, int]:
+def conv_out_hw(h: int, w: int, k: int, stride: int, padding) -> Tuple[int, int]:
+    """Output map of a conv; ``padding`` is "SAME", "VALID" or explicit
+    ``((top, bottom), (left, right))``."""
     if padding == "SAME":
         return (math.ceil(h / stride), math.ceil(w / stride))
-    # VALID
-    return ((h - k) // stride + 1, (w - k) // stride + 1)
+    if padding == "VALID":
+        padding = ((0, 0), (0, 0))
+    (t, b), (le, r) = padding
+    return ((h + t + b - k) // stride + 1, (w + le + r - k) // stride + 1)
 
 
 def conv_cost(h: int, w: int, k: int, cin: int, cout: int, stride: int,
-              padding: str = "SAME") -> dict:
+              padding="SAME") -> dict:
     """FLOPs/bytes/IMC-metadata for one conv node (per single frame)."""
     ho, wo = conv_out_hw(h, w, k, stride, padding)
     macs = ho * wo * k * k * cin * cout

@@ -117,8 +117,15 @@ def init(key, cfg: dict = YOLOV8N) -> Dict:
 # forward
 # ---------------------------------------------------------------------------
 
-def _conv(p, x, stride=1, act="silu", k=None):
-    return L.conv2d(p, x, stride=stride, act=act)
+def autopad(k: int):
+    """Ultralytics' padding of a k x k conv: k // 2 on every side.  For a
+    stride-2 3x3 conv it is not "SAME", which pads 0 before and 1 after."""
+    return ((k // 2, k // 2), (k // 2, k // 2))
+
+
+def _conv(p, x, stride=1, act="silu"):
+    return L.conv2d(p, x, stride=stride, padding=autopad(p["w"].shape[0]),
+                    act=act)
 
 
 def _c2f(p, x, shortcut: bool):
@@ -168,7 +175,7 @@ def backbone_neck(params, x):
 def _head_branch(branch, x):
     y = _conv(branch["0"], x)
     y = _conv(branch["1"], y)
-    return L.conv2d(branch["2"], y, act=None)   # plain conv, no act
+    return _conv(branch["2"], y, act=None)      # plain conv, no act
 
 
 def forward(params, x, cfg: dict = YOLOV8N, decode: bool = True):

@@ -25,6 +25,9 @@ divide, round, clip, cast), ``quant.weight`` (``quantize_weight``),
 they time the device launches; inside a function ``jax.jit`` traces, as
 the graph executor's program, they time the tracing and the counter
 counts tensors quantised per trace, once per compiled program.
+``calibrate_graph`` records one ``calibrate`` span (``node`` = the graph's
+name, ``kind`` = "<n> nodes", ``batch`` = the calibration frames) and
+counts the scales it makes in ``calibrate.scales``.
 """
 
 from __future__ import annotations
@@ -33,6 +36,7 @@ from typing import Dict, NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import obs
 
@@ -181,3 +185,27 @@ def calibrate_resnet(params: Dict, x: jnp.ndarray, cfg: dict) -> Dict[str, float
     g = jnp.mean(h, axis=(1, 2))
     rec("fc", g)
     return scales
+
+
+def calibrate_graph(g, params: Dict, x: jnp.ndarray,
+                    block: int = 16) -> Dict[str, float]:
+    """Per-tensor input scales of every CONV and MVM node of graph ``g``
+    with learned weights, from the graph's own float program over the
+    calibration frames ``x``, ``block`` frames a call: a large calibration
+    set never sits on the device as one batch of activations, and each
+    block is waited for before the next is sliced, so that no two blocks
+    are on the device at once.  A last, shorter block is run at its own
+    size, not padded."""
+    from .cnn import executor
+
+    names = executor.weighted_nodes(g)
+    with obs.span("calibrate", node=g.name, kind=f"{len(names)} nodes",
+                  batch=len(x)):
+        amax = None
+        for i in range(0, len(x), block):
+            m = executor.input_magnitudes(g, params, x[i:i + block])
+            m.block_until_ready()
+            amax = m if amax is None else jnp.maximum(amax, m)
+        scales = np.asarray(jnp.maximum(amax, 1e-8) / 127.0)
+    obs.count("calibrate.scales", len(names))
+    return {n: float(s) for n, s in zip(names, scales)}

@@ -41,12 +41,15 @@ from typing import Dict, Iterator, List, Optional
 import numpy as np
 
 #: span names, outermost first: one ``execute`` per executor call, one
-#: ``node`` per graph node, and the phases of an int8 conv or dense node
+#: ``node`` per graph node, and the phases of an int8 conv or dense node;
+#: then ``calibrate``, one per ``quant.calibrate_graph`` (set-up)
 SPAN_NAMES = ("execute", "node", "quant.act", "quant.weight", "int8.acc",
-              "dequant")
+              "dequant", "calibrate")
 #: frames served, executor programs traced, and weight tensors quantised
-#: (per trace, inside a jitted program), summed over the recording
-COUNTER_NAMES = ("execute.frames", "execute.traces", "quant.weight.tensors")
+#: (per trace, inside a jitted program); activation scales calibrated; the
+#: bytes of the executor's inputs and results; summed over the recording
+COUNTER_NAMES = ("execute.frames", "execute.traces", "quant.weight.tensors",
+                 "calibrate.scales", "execute.bytes_in", "execute.bytes_out")
 
 _NULL = contextlib.nullcontext()
 _NAN = float("nan")
@@ -172,13 +175,21 @@ def span(name: str, node: Optional[str] = None, kind: Optional[str] = None,
 
     ``node`` is the graph node the span belongs to (a phase span inherits
     its enclosing span's), ``kind`` the node's kind (for ``execute``: the
-    arithmetic mode), ``batch`` the frames of an ``execute`` call.
+    arithmetic mode), ``batch`` the frames of an ``execute`` call.  A
+    ``calibrate`` span gives the graph's name as ``node``, the number of
+    nodes it scales as ``kind`` ("<n> nodes") and its frames as ``batch``.
     """
     rec = _active
     if rec is None:
         return _NULL
     rec._name, rec._node, rec._kind, rec._batch = name, node, kind, batch
     return rec
+
+
+def on() -> bool:
+    """Whether a ``recording()`` is on: lets a caller skip work whose only
+    use is a counter."""
+    return _active is not None
 
 
 def count(name: str, n: int = 1) -> None:

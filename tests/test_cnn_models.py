@@ -86,6 +86,88 @@ class TestExecutorParity:
         rel = jnp.linalg.norm(i8 - f32) / jnp.linalg.norm(f32)
         assert rel < 0.25
 
+    def test_yolo_graph_execution_matches_reference(self, yolo64):
+        g, params, x, _ = yolo64
+        ref = yolo.forward(params, x)
+        got = executor.execute(g, params, x, mode="float")
+        assert got.shape == (2, 8 * 8 + 4 * 4 + 2 * 2, 4 + yolo.NC)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+
+    # Box and class parts of int8 YOLOv8n against float, each as the norm
+    # of the differences over that of the float answers less their mean
+    # over the batch.  Per-tensor int8 rounding at the 63 conv inputs reads
+    # about 0.15 in both parts on these 2 frames; int4 weights alone read
+    # about 1.0.  The bound lies between, with room on both sides.
+    PART_BOUND = {"box": 0.4, "cls": 0.4}
+
+    @staticmethod
+    def _part_errors(got, want):
+        out = {}
+        for part, (lo, hi) in {"box": (0, 4), "cls": (4, 4 + yolo.NC)}.items():
+            d = np.asarray(got)[..., lo:hi] - np.asarray(want)[..., lo:hi]
+            w = np.asarray(want)[..., lo:hi]
+            out[part] = np.linalg.norm(d) / np.linalg.norm(w - w.mean(0))
+        return out
+
+    def test_yolo_int8_parts_close_to_float(self, yolo64):
+        g, params, x, scales = yolo64
+        f32 = executor.execute(g, params, x, mode="float")
+        i8 = executor.execute(g, params, x, mode="int8", act_scales=scales)
+        err = self._part_errors(i8, f32)
+        assert all(err[p] < b for p, b in self.PART_BOUND.items()), err
+
+    def test_yolo_int4_weights_fail_a_part_bound(self, yolo64):
+        g, params, x, scales = yolo64
+
+        def int4(path, a):
+            if path[-1].key != "w":
+                return a
+            s = jnp.maximum(jnp.max(jnp.abs(a), axis=(0, 1, 2)), 1e-8) / 7
+            return jnp.clip(jnp.round(a / s), -7, 7) * s
+
+        p4 = jax.tree_util.tree_map_with_path(int4, params)
+        f32 = executor.execute(g, params, x, mode="float")
+        i4 = executor.execute(g, p4, x, mode="int8", act_scales=scales)
+        err = self._part_errors(i4, f32)
+        assert any(err[p] >= b for p, b in self.PART_BOUND.items()), err
+
+    def test_every_yolo_node_kind_executes(self, yolo64):
+        from repro import obs
+        _, params, x, scales = yolo64
+        g = graphs.build_yolov8n_graph(YOLO64)      # a fresh program: traced
+        with obs.recording() as rec:
+            out = executor.execute(g, params, x[:1], mode="int8",
+                                   act_scales=scales)
+        assert np.isfinite(np.asarray(out)).all()
+        r = rec.rows()
+        nodes = r["name"] == obs.SPAN_NAMES.index("node")
+        ran = {rec.label(k) for k in r["kind"][nodes]}
+        assert ran == {n.kind.name for n in g.nodes.values()} == {
+            "CONV", "MVM", "ADD", "MUL", "ACT", "CONCAT", "SPLIT", "POOL_MAX",
+            "UPSAMPLE", "RESHAPE", "SOFTMAX"}
+        assert nodes.sum() == len(g) == 233
+
+    def test_every_yolo_conv_param_resolves(self, yolo64):
+        g, params, _, _ = yolo64
+        convs = [n for n in g.nodes.values() if n.kind == OpKind.CONV]
+        assert len(convs) == 63
+        for n in convs:
+            p = executor._param_at(params, n.meta["param"])
+            k, cout = n.meta["k"], n.meta["cout"]
+            assert p["w"].shape == (k, k, n.meta["cin_kk"] // (k * k), cout)
+            assert p["b"].shape == (cout,)
+        leaves = len(jax.tree_util.tree_leaves(params))
+        assert leaves == 2 * len(convs)         # every weight is read
+        dfl = [n for n in g.nodes.values() if n.kind == OpKind.MVM]
+        assert [n.name for n in dfl] == ["dfl.conv"]
+        assert dfl[0].meta["param"] is None
+        assert dfl[0].meta["weights"] == [[float(i)] for i in range(16)]
+
+    def test_yolo_graph_needs_a_multiple_of_32(self):
+        with pytest.raises(ValueError):
+            graphs.build_yolov8n_graph(dict(yolo.YOLOV8N, image_hw=(48, 64)))
+
     def test_yolo_forward_shapes(self):
         params = yolo.init(jax.random.PRNGKey(0))
         x = jax.random.normal(jax.random.PRNGKey(1), (1, 64, 64, 3))
@@ -95,6 +177,19 @@ class TestExecutorParity:
         raw = yolo.forward(params, x, decode=False)
         assert [r.shape for r in raw] == [
             (1, 8, 8, 144), (1, 4, 4, 144), (1, 2, 2, 144)]
+
+
+YOLO64 = dict(yolo.YOLOV8N, image_hw=(64, 64))
+
+
+@pytest.fixture(scope="module")
+def yolo64():
+    """YOLOv8n's graph at 64x64, seeded ``yolo.init`` weights, 2 frames
+    and their scales from ``quant.calibrate_graph``."""
+    params = yolo.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, 64, 3))
+    g = graphs.build_yolov8n_graph(YOLO64)
+    return g, params, x, quant.calibrate_graph(g, params, x)
 
 
 @pytest.fixture(scope="module")
@@ -117,9 +212,15 @@ def _traced(fn):
 class TestCompiledExecutor:
     """One jitted program per graph, mode and set of scaled nodes."""
 
-    def test_second_call_traces_nothing(self, resnet8_int8):
-        cfg, params, x, scales = resnet8_int8
-        g = graphs.build_resnet_graph(cfg)
+    @pytest.mark.parametrize("model", ["resnet8", "yolov8n"])
+    def test_second_call_traces_nothing(self, request, model):
+        if model == "resnet8":
+            cfg, params, x, scales = request.getfixturevalue("resnet8_int8")
+            g = graphs.build_resnet_graph(cfg)
+        else:
+            _, params, x, scales = request.getfixturevalue("yolo64")
+            g = graphs.build_yolov8n_graph(YOLO64)
+        x = x[:1]                               # a stream's single frame
         run = lambda: executor.execute(g, params, x, mode="int8",  # noqa: E731
                                        act_scales=scales)
         first, calls, traces = _traced(run)
@@ -256,6 +357,49 @@ class TestQuant:
         noisy = quant.quantized_matmul(x, w, noise_std=5.0,
                                        key=jax.random.PRNGKey(2))
         assert not jnp.allclose(clean, noisy)
+
+    @pytest.mark.parametrize("model", ["resnet8", "yolov8n"])
+    def test_calibrate_graph_covers_every_weighted_node(self, request, model):
+        from repro import obs
+        if model == "resnet8":
+            cfg, params, x, _ = request.getfixturevalue("resnet8_int8")
+            g = graphs.build_resnet_graph(cfg)
+        else:
+            g, params, x, _ = request.getfixturevalue("yolo64")
+        with obs.recording() as rec:
+            scales = quant.calibrate_graph(g, params, x, block=1)
+        weighted = {n.name for n in g.nodes.values()
+                    if n.kind in (OpKind.CONV, OpKind.MVM)
+                    and n.meta.get("param") is not None}
+        assert set(scales) == weighted
+        assert "dfl.conv" not in scales         # fixed weights: not scaled
+        assert all(s > 0 for s in scales.values())
+        assert rec.counters["calibrate.scales"] == len(weighted)
+        r = rec.rows()
+        cal = np.flatnonzero(r["name"] == obs.SPAN_NAMES.index("calibrate"))
+        assert len(cal) == 1
+        assert rec.label(r["node"][cal[0]]) == g.name
+        assert rec.label(r["kind"][cal[0]]) == f"{len(weighted)} nodes"
+        assert r["batch"][cal[0]] == len(x)
+
+    def test_calibrate_graph_in_blocks_takes_the_largest(self, yolo64):
+        g, params, x, whole = yolo64
+        one = [quant.calibrate_graph(g, params, x[i:i + 1]) for i in (0, 1)]
+        for name, s in quant.calibrate_graph(g, params, x, block=1).items():
+            assert s == max(one[0][name], one[1][name])
+            assert s == pytest.approx(whole[name], rel=1e-5)
+
+    def test_calibrate_graph_matches_calibrate_resnet(self, resnet8_int8):
+        # The same magnitudes, from the graph's jitted float program rather
+        # than an eager replay of resnet.forward; XLA rounds the two
+        # differently in the last bit (one scale of ResNet-8's ten on the
+        # CPU), so calibrate_resnet keeps its own replay.
+        cfg, params, x, replay = resnet8_int8
+        scales = quant.calibrate_graph(graphs.build_resnet_graph(cfg),
+                                       params, x)
+        assert set(scales) == set(replay)
+        for name, s in replay.items():
+            assert scales[name] == pytest.approx(s, rel=1e-6), name
 
     def test_calibration_scales_cover_layers(self):
         cfg = resnet.RESNET8

@@ -49,6 +49,14 @@ def test_off_records_nothing_and_returns_the_shared_null_context():
     assert obs._active is None
 
 
+def test_on_only_inside_a_recording():
+    # the executor counts its bytes only when this says a recording is on
+    assert not obs.on()
+    with obs.recording():
+        assert obs.on()
+    assert not obs.on()
+
+
 def test_off_allocates_nothing_per_span():
     def spans(n):
         for _ in range(n):
@@ -131,7 +139,40 @@ def test_warm_call_records_its_execute_span_alone(resnet8, recorded):
     r = rec.rows()
     assert [obs.SPAN_NAMES[k] for k in r["name"]] == ["execute"]
     assert rec.counters == {"execute.frames": 2, "execute.traces": 0,
-                            "quant.weight.tensors": 0}
+                            "quant.weight.tensors": 0, "calibrate.scales": 0,
+                            "execute.bytes_in": 2 * 32 * 32 * 3 * 4,
+                            "execute.bytes_out": 2 * 10 * 4}
+
+
+def test_bytes_in_and_out_counted_from_shapes_per_call(resnet8, recorded):
+    # float32 frames in and logits out, counted in the call that traces
+    # and in warm calls alike, without waiting for the device
+    _, rec, _, out = recorded
+    assert rec.counters["execute.bytes_in"] == 2 * 32 * 32 * 3 * 4
+    assert rec.counters["execute.bytes_out"] == out.nbytes == 2 * 10 * 4
+    _, run = resnet8
+    with obs.recording() as rec3:
+        for _ in range(3):
+            run()
+    assert rec3.counters["execute.bytes_in"] == 3 * 2 * 32 * 32 * 3 * 4
+    assert rec3.counters["execute.bytes_out"] == 3 * 2 * 10 * 4
+
+
+def test_calibrate_records_its_span_and_scales():
+    cfg = resnet.RESNET8
+    params = resnet.init(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 32, 32, 3))
+    g = graphs.build_resnet_graph(cfg)
+    with obs.recording() as rec:
+        scales = quant.calibrate_graph(g, params, x, block=2)
+    r = rec.rows()
+    top = r["parent"] == -1
+    assert [obs.SPAN_NAMES[k] for k in r["name"][top]] == ["calibrate"]
+    assert rec.label(r["node"][top][0]) == g.name == "resnet8"
+    assert rec.label(r["kind"][top][0]) == "10 nodes"
+    assert r["batch"][top][0] == 4
+    assert rec.counters["calibrate.scales"] == len(scales) == 10
+    assert rec.counters["execute.frames"] == 0   # no execute call
 
 
 def test_one_node_span_per_graph_node_in_topological_order(recorded):
