@@ -47,9 +47,11 @@ SPAN_NAMES = ("execute", "node", "quant.act", "quant.weight", "int8.acc",
               "dequant", "calibrate")
 #: frames served, executor programs traced, and weight tensors quantised
 #: (per trace, inside a jitted program); activation scales calibrated; the
-#: bytes of the executor's inputs and results; summed over the recording
+#: bytes of the executor's inputs and results; executor calls whose host
+#: frames were put as a flat [rows, 128] view; summed over the recording
 COUNTER_NAMES = ("execute.frames", "execute.traces", "quant.weight.tensors",
-                 "calibrate.scales", "execute.bytes_in", "execute.bytes_out")
+                 "calibrate.scales", "execute.bytes_in", "execute.bytes_out",
+                 "execute.flat_puts")
 
 _NULL = contextlib.nullcontext()
 _NAN = float("nan")

@@ -200,6 +200,14 @@ def resnet8_int8():
     return cfg, params, x, quant.calibrate_resnet(params, x, cfg)
 
 
+@pytest.fixture(scope="module")
+def resnet18_int8():
+    cfg = resnet.RESNET18_CIFAR
+    params = resnet.init(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32, 3))
+    return cfg, params, x, quant.calibrate_resnet(params, x, cfg)
+
+
 def _traced(fn):
     """``fn()``'s result and the ``execute`` spans and programs traced."""
     from repro import obs
@@ -239,9 +247,12 @@ class TestCompiledExecutor:
         out, _, traces = _traced(run)
         assert out.shape == (3, 10) and traces == 1
         assert _traced(run)[2] == 0
-        # host numpy frames of a warm shape reuse the program
-        assert _traced(lambda: executor.execute(
-            g, params, np.asarray(x3), mode="int8", act_scales=scales))[2] == 0
+        # host numpy frames are put as a flat view: one program of their
+        # own a shape, reused by the next call
+        host = lambda: executor.execute(  # noqa: E731
+            g, params, np.asarray(x3), mode="int8", act_scales=scales)
+        assert _traced(host)[2] == 1
+        assert _traced(host)[2] == 0
 
     @pytest.mark.parametrize("mutation", ["node", "edge"])
     def test_graph_mutation_traces_again(self, resnet8_int8, mutation):
@@ -290,12 +301,51 @@ class TestCompiledExecutor:
         rel = np.linalg.norm(compiled - eager) / np.linalg.norm(eager)
         assert rel <= 1e-5
 
+    # (model, batch, frame side): 32x32x3 and 64x64x3 frames are multiples
+    # of 128 floats and are put as a flat view; 30x30x3 (2,700) is not
+    @pytest.mark.parametrize("mode", ["float", "int8"])
+    @pytest.mark.parametrize("model,batch,side", [
+        ("resnet8", 1, 32), ("resnet8", 8, 32), ("resnet18", 1, 32),
+        ("resnet18", 8, 32), ("yolov8n", 2, 64), ("resnet8", 1, 30)])
+    def test_host_frames_match_device_frames(self, request, model, batch,
+                                             side, mode):
+        from repro import obs
+        if model == "yolov8n":
+            _, params, _, scales = request.getfixturevalue("yolo64")
+            g = graphs.build_yolov8n_graph(YOLO64)
+        else:
+            cfg, params, _, scales = request.getfixturevalue(f"{model}_int8")
+            g = graphs.build_resnet_graph(cfg)
+        host = np.random.default_rng(batch).standard_normal(
+            (batch, side, side, 3), dtype=np.float32)
+        flat = host.size % executor.LANES == 0
+        s = scales if mode == "int8" else None
+
+        def run(x):
+            with obs.recording() as rec:
+                out = np.asarray(executor.execute(g, params, x, mode=mode,
+                                                  act_scales=s))
+            return out, rec.counters
+
+        on_device, counters = run(jnp.asarray(host))
+        assert counters["execute.flat_puts"] == 0
+        got, counters = run(host)
+        assert counters["execute.flat_puts"] == int(flat)
+        np.testing.assert_array_equal(got, on_device)
+        again, counters = run(host)
+        assert counters["execute.traces"] == 0
+        assert counters["execute.flat_puts"] == int(flat)
+        np.testing.assert_array_equal(again, got)
+
     def test_inside_an_outer_jit_twice_leaks_no_tracer(self, resnet8_int8):
+        from repro import obs
         cfg, params, x, scales = resnet8_int8
         g = graphs.build_resnet_graph(cfg)      # the scale memo is made inside
         fn = jax.jit(lambda p, x: executor.execute(g, p, x, mode="int8",
                                                    act_scales=scales))
-        a = np.asarray(fn(params, x))
+        with obs.recording() as rec:            # host frames, traced: no view
+            a = np.asarray(fn(params, np.asarray(x)))
+        assert rec.counters["execute.flat_puts"] == 0
         b = np.asarray(fn(params, x[:1]))       # a second trace of the outer jit
         eager = np.asarray(executor.execute(g, params, x, mode="int8",
                                             act_scales=scales))

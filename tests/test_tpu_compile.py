@@ -105,6 +105,39 @@ def test_int8_executor_compiles_resnet18(one_chip):
     assert compiled.memory_analysis() is not None
 
 
+@pytest.mark.parametrize("batch", [256, 1])
+def test_served_frames_reach_the_program_in_host_order(one_chip, batch):
+    # the int8 ResNet-18 program as served: host frames put as their
+    # [rows, 128] view, whose layout must be the host buffer's linear order
+    # (row-major, 8 x 128 tiles), so that the put needs no host transpose
+    cfg = resnet.RESNET18_CIFAR
+    g = graphs.build_resnet_graph(cfg)
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: resnet.init(jax.random.PRNGKey(0), cfg)))
+    names = tuple(sorted(executor.weighted_nodes(g)))
+    shape = (batch, 32, 32, 3)
+    rows = batch * 32 * 32 * 3 // executor.LANES
+    program = executor._program(g, "int8", names)
+    scales = _spec(one_chip, (len(names),), jnp.float32)
+    flat = program.lower(
+        params, _spec(one_chip, (rows, executor.LANES), jnp.float32),
+        scales, shape).compile()
+    layout = flat.input_formats[0][1].layout
+    assert layout.major_to_minor == (0, 1)
+    assert layout.tiling == ((8, 128),)
+    # the device re-lays the frames at little cost: within 5% of the bytes
+    # the program accesses with NHWC frames (a plain reshape: +15% at 256)
+    nhwc = program.lower(params, _spec(one_chip, shape, jnp.float32),
+                         scales, None).compile()
+    assert _bytes_accessed(flat) <= 1.05 * _bytes_accessed(nhwc)
+
+
+def _bytes_accessed(compiled):
+    cost = compiled.cost_analysis()
+    return (cost[0] if isinstance(cost, list) else cost)["bytes accessed"]
+
+
 def _skip_on_tpu():
     if jax.devices()[0].platform == "tpu":
         pytest.skip("JAX sees a TPU: the guard has nothing to refuse")
