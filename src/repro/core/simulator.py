@@ -167,6 +167,17 @@ _VECTOR_MIN = 192
 _DEBUG_SAMPLES: Optional[list] = None
 
 
+def _running_sum(xs) -> float:
+    """``xs`` added left to right.  Python 3.12's ``sum`` of floats
+    compensates its rounding (Neumaier), so it differs from this plain
+    running sum in the last place; ``run`` reports this one on every
+    Python, as the goldens pin."""
+    total = 0.0
+    for x in xs:
+        total += x
+    return total
+
+
 def slo_headroom(rate: float, latency: float,
                  min_rate: Optional[float] = None,
                  max_latency: Optional[float] = None) -> float:
@@ -359,7 +370,7 @@ class IMCESimulator:
         )
         k = len(sojourns) // 4
         steady = sojourns[k:] or sojourns
-        latency = sum(steady) / len(steady)
+        latency = _running_sum(steady) / len(steady)
         in_flight = self.max_in_flight or (len(assignment.pus) + 2)
         makespan, comps_by_stream, busy, _, busy_by_stream = self._run_streams(
             assignment, frames=frames, in_flight=in_flight
@@ -380,7 +391,7 @@ class IMCESimulator:
                 for p, v in d.items():
                     total_busy[p] += v
         else:
-            total_busy = {p: sum(iv[1] - iv[0] for iv in ivs)
+            total_busy = {p: _running_sum(iv[1] - iv[0] for iv in ivs)
                           for p, ivs in busy.items()}
         res = SimResult(
             latency=latency,
@@ -391,7 +402,7 @@ class IMCESimulator:
             frames=frames,
             busy=total_busy,
             utilization=utilization,
-            mean_utilization=sum(utilization.values()) / max(len(utilization), 1),
+            mean_utilization=_running_sum(utilization.values()) / max(len(utilization), 1),
             per_frame_busy=per_frame_busy,
             bound_interval=bound,
             meta={"algorithm": assignment.algorithm, "in_flight": in_flight},
@@ -1322,7 +1333,7 @@ class MultiTenantSimulator(IMCESimulator):
             if not xs:
                 return 0.0
             steady = xs[len(xs) // 4:] or xs
-            return sum(steady) / len(steady)
+            return _running_sum(steady) / len(steady)
 
         merged = sorted(t for comps in completions.values() for t in comps)
         interval, util_window = self._steady_state(merged)
@@ -1332,7 +1343,7 @@ class MultiTenantSimulator(IMCESimulator):
         per_frame_busy = self._per_frame_busy(assignment)
         bound = max(per_frame_busy.values()) if per_frame_busy else 0.0
 
-        fleet_busy = sum(sum(d.values()) for d in tenant_busy.values())
+        fleet_busy = _running_sum(_running_sum(d.values()) for d in tenant_busy.values())
         tenant_load = self._cached_tenant_load(assignment)
         per_tenant: Dict[str, TenantMetrics] = {}
         for t in tenants:
@@ -1346,7 +1357,7 @@ class MultiTenantSimulator(IMCESimulator):
                 latency=steady_mean(lat_sojourns.get(t, [])),
                 bound_interval=max(tenant_load.get(t, {0: 0.0}).values()),
                 busy=t_busy,
-                utilization_share=(sum(t_busy.values()) / fleet_busy
+                utilization_share=(_running_sum(t_busy.values()) / fleet_busy
                                    if fleet_busy > 0 else 0.0),
                 injected_rate=None if rates is None else rates[t],
             )
@@ -1357,11 +1368,11 @@ class MultiTenantSimulator(IMCESimulator):
                 for p, v in d.items():
                     total_busy[p] += v
         else:
-            total_busy = {p: sum(iv[1] - iv[0] for iv in ivs)
+            total_busy = {p: _running_sum(iv[1] - iv[0] for iv in ivs)
                           for p, ivs in busy_iv.items()}
         # aggregate sojourn latency: completion-weighted tenant mean
         agg_latency = (
-            sum(m.latency * max(m.frames, 1) for m in per_tenant.values())
+            _running_sum(m.latency * max(m.frames, 1) for m in per_tenant.values())
             / max(sum(max(m.frames, 1) for m in per_tenant.values()), 1))
         res = SimResult(
             latency=agg_latency,
@@ -1372,7 +1383,7 @@ class MultiTenantSimulator(IMCESimulator):
             frames=sum(len(c) for c in completions.values()),
             busy=total_busy,
             utilization=utilization,
-            mean_utilization=sum(utilization.values()) / max(len(utilization), 1),
+            mean_utilization=_running_sum(utilization.values()) / max(len(utilization), 1),
             per_frame_busy=per_frame_busy,
             bound_interval=bound,
             meta={"algorithm": assignment.algorithm, "in_flight": in_flight,

@@ -2,8 +2,13 @@
 
 The scheduler decides *where* nodes run (timing is emulated by the DES);
 numerics are placement-invariant, so the executor walks the DAG in
-topological order and evaluates each node with jnp ops, reading conv/fc
-parameters from the model pytree via ``node.meta["param"]`` paths.
+topological order and evaluates each node with jnp ops.  One rule, the
+same for every model: a node's operands are its ``meta["inputs"]``, in
+that order (``graphs.py`` documents the pairs; ``[]`` reads the frames),
+and conv/fc parameters come from the model pytree via
+``node.meta["param"]`` paths.  A node without ``inputs``, or whose
+``inputs`` do not name its predecessors, is refused with a
+``ValueError`` naming it.
 
 The walk is traced once into one ``jax.jit`` program per graph, mode and
 set of node names in ``act_scales``, kept in ``g.scratch()`` (so any
@@ -20,9 +25,9 @@ or channel minor, which the runtime does by transposing them on a host
 thread at every put.  A device array, a tracer of an outer ``jit`` or any
 other size goes as it is.  Each node's operations carry its name as a
 ``jax.named_scope``, so device time can be put down to graph nodes.
-``input_magnitudes``, which ``quant.calibrate_graph`` calls, is a second
-program per graph: the float walk, returning the largest input magnitude
-of each node with learned weights.
+``input_magnitudes``, through which ``quant`` calibrates every model, is
+a second program per graph: the float walk, returning the largest input
+magnitude of each node with learned weights.
 
 Two arithmetic modes:
 * ``mode="float"`` — float32 reference.
@@ -34,17 +39,16 @@ Numerics parity with the un-scheduled reference model is asserted in
 tests (float mode: ``resnet.forward`` and ``yolo.forward`` within float
 rounding; int8 mode: bounded quantization error).
 
-Node kinds: CONV, MVM, ADD, GLOBAL_POOL, INPUT and OUTPUT (the ResNet
-graphs), and ACT, MUL, CONCAT, SPLIT, POOL_MAX, UPSAMPLE, RESHAPE and
-SOFTMAX (YOLOv8n; ``graphs.py`` documents their metadata).  A node reads
-its operands in the order of ``meta["inputs"]`` where the graph gives it,
-else in the order of its predecessors.  In int8 mode only the nodes with
-learned weights are quantised: each CONV's input per tensor and its
-weights per output channel, and each MVM with a ``param``.  Everything
-else runs in float32 by design, inside the same program: YOLOv8n's SiLU
-(ACT + MUL), residual adds, concats, pools, upsamples and its whole
-decode (the DFL softmax, the fixed-weight ``dfl.conv``, dist2bbox and the
-class sigmoid), none of which has learned weights.
+Node kinds: CONV, MVM, ADD and GLOBAL_POOL (the ResNet graphs); ACT, MUL,
+CONCAT, SPLIT, POOL_MAX, UPSAMPLE, RESHAPE and SOFTMAX (YOLOv8n;
+``graphs.py`` documents their metadata); INPUT (the frames) and OUTPUT
+(its operand).  In int8 mode only the nodes with learned weights are
+quantised: each CONV's input per tensor and its weights per output
+channel, and each MVM with a ``param``.  Everything else runs in float32
+by design, inside the same program: YOLOv8n's SiLU (ACT + MUL), residual
+adds, concats, pools, upsamples and its whole decode (the DFL softmax,
+the fixed-weight ``dfl.conv``, dist2bbox and the class sigmoid), none of
+which has learned weights.
 
 Spans (``repro.obs``, recorded only inside ``obs.recording()``): one
 ``execute`` per call (``kind`` = the mode, ``batch`` = frames).  Under it,
@@ -140,19 +144,16 @@ def input_magnitudes(g: Graph, params: Dict, x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _steps(g: Graph):
-    """Each node in topological order with its operands as (node id,
-    part) pairs: ``meta["inputs"]`` where the node lists them (``part``
-    picks a SPLIT's output), else its predecessors (``part`` None)."""
+    """Each node in topological order with its operands, ``meta["inputs"]``,
+    as (node id, part) pairs (``part`` picks a SPLIT's output)."""
     ids = {node.name: nid for nid, node in g.nodes.items()}
     steps = []
     for nid in g.topo_order():
         node, preds = g.nodes[nid], g.predecessors(nid)
         refs = node.meta.get("inputs")
-        if refs is None:
-            steps.append((node, [(p, None) for p in preds]))
-            continue
-        ops = [(ids[name], part) for name, part in refs]
-        if sorted({i for i, _ in ops}) != sorted(preds):
+        ops = [] if refs is None else [(ids[name], part)
+                                       for name, part in refs]
+        if refs is None or sorted({i for i, _ in ops}) != sorted(preds):
             raise ValueError(f"node {node.name}: meta inputs {refs} do not "
                              "name its predecessors")
         steps.append((node, ops))

@@ -7,13 +7,18 @@ emits a ``repro.core.Graph`` whose nodes carry:
   tiling meta) consumed by ``repro.core.cost.CostModel``;
 * execution metadata consumed by ``repro.models.cnn.executor``, so that a
   scheduled graph remains a *runnable program*, not just a cost table:
-  each conv and dense node's ``meta["param"]`` path into the model's
-  parameter pytree and its op attributes, and in the YOLOv8n graph every
-  node's ordered operands (``meta["inputs"]``) and the attributes of its
-  other kinds (see the YOLOv8n section below).
+  every node's ordered operands (``meta["inputs"]``), each conv and dense
+  node's ``meta["param"]`` path into the model's parameter pytree, and
+  the attributes of each kind (see the YOLOv8n section below).
+
+``meta["inputs"]`` lists pairs of (producer's name, part): a SPLIT yields
+a tuple of slices and ``part`` picks one, for any other producer it is
+None.  A node with no operands (``[]``) reads the frames.  The graph keeps
+one edge per producer, so which slice of a split a node takes is told
+only here.
 
 Node numbering is topological and matches the paper's Table I ids for
-ResNet18-CIFAR (verified in tests/test_cnn_graphs.py).
+ResNet18-CIFAR (verified in tests/test_cnn_models.py).
 """
 
 from __future__ import annotations
@@ -27,15 +32,24 @@ from .resnet import RESNET8, RESNET18_CIFAR
 from .yolo import CH, NC, REG_MAX, STRIDES, YOLOV8N, autopad
 
 
-def _add_conv(g: Graph, name: str, deps: List[int], h: int, w: int, k: int,
+Ref = Union[int, Tuple[int, int]]       # node id, or (SPLIT id, part)
+
+
+def _operands(g: Graph, refs: List[Ref]) -> Tuple[List[int], list]:
+    """The deps (one per producer, in order) and ``meta["inputs"]`` of a
+    node reading ``refs``."""
+    pairs = [r if isinstance(r, tuple) else (r, None) for r in refs]
+    return ([nid for nid, _ in pairs],
+            [(g.nodes[nid].name, part) for nid, part in pairs])
+
+
+def _add_conv(g: Graph, name: str, refs: List[Ref], h: int, w: int, k: int,
               cin: int, cout: int, stride: int, act: Optional[str],
-              param: tuple, padding="SAME",
-              inputs: Optional[list] = None) -> Tuple[int, int, int]:
+              param: tuple, padding="SAME") -> Tuple[int, int, int]:
     cost = L.conv_cost(h, w, k, cin, cout, stride, padding)
     meta = dict(cost.pop("meta"))
     meta.update(param=param, stride=stride, act=act, padding=padding, k=k)
-    if inputs is not None:
-        meta["inputs"] = inputs
+    deps, meta["inputs"] = _operands(g, refs)
     n = g.add(name, OpKind.CONV, deps=deps, fused_act=act, meta=meta, **cost)
     ho, wo = meta["out_hw"]
     return n.node_id, ho, wo
@@ -66,28 +80,28 @@ def build_resnet_graph(cfg: dict) -> Graph:
             c2, h2, w2 = _add_conv(
                 g, f"s{si}b{bi}.conv2", [c1], h1, w1, 3, width, width, 1,
                 None, ("stages", si, bi, "conv2"))
-            add_deps = [c2]
             if needs_down:
-                d, _, _ = _add_conv(
+                identity, _, _ = _add_conv(
                     g, f"s{si}b{bi}.down", [identity], h, w, 1, cin, width,
                     stride, None, ("stages", si, bi, "down"))
-                add_deps.append(d)
-            else:
-                add_deps.append(identity)
             cost = L.elem_cost(h2 * w2 * width)
             meta = dict(cost.pop("meta"))
-            meta.update(act="relu")
-            add = g.add(f"s{si}b{bi}.add", OpKind.ADD, deps=add_deps,
+            deps, inputs = _operands(g, [c2, identity])
+            meta.update(act="relu", inputs=inputs)
+            add = g.add(f"s{si}b{bi}.add", OpKind.ADD, deps=deps,
                         fused_act="relu", meta=meta, **cost)
             prev, h, w, cin = add.node_id, h2, w2, width
 
     cost = L.elem_cost(cin)
     cost.pop("meta")
-    gap = g.add("gap", OpKind.GLOBAL_POOL, deps=[prev], meta={}, **cost)
+    deps, inputs = _operands(g, [prev])
+    gap = g.add("gap", OpKind.GLOBAL_POOL, deps=deps,
+                meta={"inputs": inputs}, **cost)
     fc_cost = L.dense_cost(cin, cfg["num_classes"])
     meta = dict(fc_cost.pop("meta"))
-    meta.update(param=("fc",))
-    g.add("fc", OpKind.MVM, deps=[gap.node_id], meta=meta, **fc_cost)
+    deps, inputs = _operands(g, [gap.node_id])
+    meta.update(param=("fc",), inputs=inputs)
+    g.add("fc", OpKind.MVM, deps=deps, meta=meta, **fc_cost)
     g.validate()
     return g
 
@@ -117,13 +131,11 @@ TABLE1_IMC_NODE_IDS = frozenset(
 # excluding it).  The three detection scales are the paper's "3 parallel
 # main branches".
 #
-# Execution metadata.  Each node lists its operands in order as
-# ``meta["inputs"]``, pairs of (producer's name, part): a SPLIT yields a
-# tuple of slices and ``part`` picks one, for any other producer it is
-# None.  The graph keeps one edge per producer, so which half of a C2f
-# split its concat and its first bottleneck take is told only here.
-# Layout is NHWC, and (batch, anchors, ...) after the head's reshapes;
-# every ``axis`` counts the batch.  Per kind:
+# Execution metadata.  Each node lists its operands in ``meta["inputs"]``
+# (module docstring): which half of a C2f split its concat and its first
+# bottleneck take is told there.  Layout is NHWC, and (batch, anchors,
+# ...) after the head's reshapes; every ``axis`` counts the batch.  Per
+# kind:
 #
 #   CONV      param (path into ``yolo.init``'s pytree), stride, padding
 #             (explicit, ``yolo.autopad``: k // 2 on every side)
@@ -144,9 +156,6 @@ TABLE1_IMC_NODE_IDS = frozenset(
 # (anchors, 1), computed here.
 # ===========================================================================
 
-Ref = Union[int, Tuple[int, int]]       # node id, or (SPLIT id, part)
-
-
 class _Emit:
     """Stateful helper emitting ONNX-level nodes with cost and execution
     metadata; operands are ``Ref``s."""
@@ -154,19 +163,12 @@ class _Emit:
     def __init__(self, g: Graph):
         self.g = g
 
-    def inputs(self, refs: List[Ref]) -> Tuple[List[int], list]:
-        """The deps (one per producer, in order) and ``meta["inputs"]``."""
-        pairs = [r if isinstance(r, tuple) else (r, None) for r in refs]
-        return ([nid for nid, _ in pairs],
-                [(self.g.nodes[nid].name, part) for nid, part in pairs])
-
     def conv(self, name, path, src: Optional[Ref], h, w, k, cin, cout,
              stride=1):
         """A plain conv reading ``src`` (the frames where None)."""
-        deps, inputs = self.inputs([] if src is None else [src])
         pad = [list(side) for side in autopad(k)]
-        return _add_conv(self.g, name, deps, h, w, k, cin, cout, stride,
-                         None, param=path, padding=pad, inputs=inputs)
+        return _add_conv(self.g, name, [] if src is None else [src], h, w, k,
+                         cin, cout, stride, None, param=path, padding=pad)
 
     def conv_module(self, name, path, src, h, w, k, cin, cout, stride=1):
         """Conv + Sigmoid + Mul (SiLU) -> returns (mul_id, ho, wo)."""
@@ -181,7 +183,7 @@ class _Emit:
     def node(self, name, kind, refs: List[Ref], n_elems, **meta):
         cost = L.elem_cost(n_elems)
         cost.pop("meta")
-        deps, meta["inputs"] = self.inputs(refs)
+        deps, meta["inputs"] = _operands(self.g, refs)
         return self.g.add(name, kind, deps=deps, meta=meta, **cost).node_id
 
     def c2f(self, name, src, h, w, cin, cout, n, shortcut):
@@ -310,7 +312,7 @@ def build_yolov8n_graph(cfg: dict = YOLOV8N) -> Graph:
     d3 = e.node("dfl.softmax", OpKind.SOFTMAX, [d2], dfl_el, axis=-1)
     dfl_cost = L.dense_cost(REG_MAX, 1)
     dfl_meta = dict(dfl_cost.pop("meta"))
-    deps, inputs = e.inputs([d3])
+    deps, inputs = _operands(g, [d3])
     dfl_meta.update(param=None, n_vectors=anchors * 4, inputs=inputs,
                     weights=[[float(i)] for i in range(REG_MAX)])
     dfl_cost["flops"] = 2.0 * dfl_el

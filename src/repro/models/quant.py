@@ -15,6 +15,10 @@ All functions are pure-jnp and jit-safe; the Pallas kernel in
 ``repro.kernels.imc_mvm`` implements the same integer semantics on TPU
 and is tested against ``quantized_matmul`` bit-exactly.
 
+Calibration has one path for every model: ``calibrate_graph`` takes each
+weighted node's input scale from the deployment graph's own float
+program, and ``calibrate_resnet`` is it on ``graphs.build_resnet_graph``.
+
 Spans (``repro.obs``, recorded only inside ``obs.recording()``):
 ``quantized_conv2d`` and ``quantized_matmul`` time their four phases as
 ``quant.act`` (activation scale if computed, the scale as a float32 array,
@@ -157,34 +161,10 @@ def quantized_conv2d(x: jnp.ndarray, w: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 def calibrate_resnet(params: Dict, x: jnp.ndarray, cfg: dict) -> Dict[str, float]:
-    """Record per-layer input activation scales on a calibration batch by
-    replaying the reference forward pass."""
-    scales: Dict[str, float] = {}
+    """``calibrate_graph`` of the deployment graph of the ResNet ``cfg``."""
+    from .cnn import graphs
 
-    # trace manually, mirroring resnet.forward
-    from .cnn import layers as L
-
-    def rec(name, t):
-        scales[name] = float(act_scale(t))
-
-    rec("stem", x)
-    h = L.conv2d(params["stem"], x, stride=1, act="relu")
-    for si, blocks in enumerate(params["stages"]):
-        for bi, block in enumerate(blocks):
-            stride = 2 if (si > 0 and bi == 0) else 1
-            identity = h
-            rec(f"s{si}b{bi}.conv1", h)
-            y = L.conv2d(block["conv1"], h, stride=stride, act="relu")
-            rec(f"s{si}b{bi}.conv2", y)
-            y = L.conv2d(block["conv2"], y, stride=1, act=None)
-            if "down" in block:
-                rec(f"s{si}b{bi}.down", identity)
-                identity = L.conv2d(block["down"], identity, stride=stride,
-                                    act=None)
-            h = jax.nn.relu(y + identity)
-    g = jnp.mean(h, axis=(1, 2))
-    rec("fc", g)
-    return scales
+    return calibrate_graph(graphs.build_resnet_graph(cfg), params, x)
 
 
 def calibrate_graph(g, params: Dict, x: jnp.ndarray,

@@ -10,6 +10,7 @@ from helpers import given, settings, st
 from repro.core.graph import OpKind, PUType
 from repro.models import quant
 from repro.models.cnn import executor, graphs, resnet, yolo
+from repro.models.cnn import layers as L
 from repro.models.cnn.layers import count_params
 
 
@@ -217,6 +218,32 @@ def _traced(fn):
     return np.asarray(out), calls, rec.counters["execute.traces"]
 
 
+class TestOperands:
+    """Every graph names each node's operands; the executor reads nothing
+    else."""
+
+    @pytest.mark.parametrize("build", [
+        lambda: graphs.build_resnet_graph(resnet.RESNET8),
+        lambda: graphs.build_resnet_graph(resnet.RESNET18_CIFAR),
+        lambda: graphs.build_yolov8n_graph(YOLO64)],
+        ids=["resnet8", "resnet18_cifar", "yolov8n"])
+    def test_every_node_lists_its_predecessors_as_inputs(self, build):
+        g = build()
+        for nid, node in g.nodes.items():
+            assert "inputs" in node.meta, node.name
+            named = {name for name, _ in node.meta["inputs"]}
+            assert named == {g.nodes[p].name for p in g.predecessors(nid)}, \
+                node.name
+
+    def test_a_node_without_inputs_is_refused(self, resnet8_int8):
+        cfg, params, x, _ = resnet8_int8
+        g = graphs.build_resnet_graph(cfg)
+        add = next(n for n in g.nodes.values() if n.name == "s1b0.add")
+        del add.meta["inputs"]
+        with pytest.raises(ValueError, match=r"node s1b0\.add: meta inputs"):
+            executor.execute(g, params, x, mode="float")
+
+
 class TestCompiledExecutor:
     """One jitted program per graph, mode and set of scaled nodes."""
 
@@ -263,7 +290,8 @@ class TestCompiledExecutor:
         before, _, _ = _traced(run)
         sink = g.topo_order()[-1]
         if mutation == "node":
-            g.add("out2", OpKind.OUTPUT, deps=[sink])   # a new sink, same logits
+            g.add("out2", OpKind.OUTPUT, deps=[sink],   # a new sink, same logits
+                  meta={"inputs": [(g.nodes[sink].name, None)]})
         else:
             g.add_edge(g.predecessors(sink)[0], sink)   # present: invalidates only
         after, _, traces = _traced(run)
@@ -439,17 +467,44 @@ class TestQuant:
             assert s == max(one[0][name], one[1][name])
             assert s == pytest.approx(whole[name], rel=1e-5)
 
-    def test_calibrate_graph_matches_calibrate_resnet(self, resnet8_int8):
-        # The same magnitudes, from the graph's jitted float program rather
-        # than an eager replay of resnet.forward; XLA rounds the two
-        # differently in the last bit (one scale of ResNet-8's ten on the
-        # CPU), so calibrate_resnet keeps its own replay.
-        cfg, params, x, replay = resnet8_int8
-        scales = quant.calibrate_graph(graphs.build_resnet_graph(cfg),
-                                       params, x)
-        assert set(scales) == set(replay)
-        for name, s in replay.items():
-            assert scales[name] == pytest.approx(s, rel=1e-6), name
+    @staticmethod
+    def _replayed_scales(params, x):
+        """The oracle: each conv and dense input's scale from an eager
+        replay of ``resnet.forward``, written out block by block."""
+        scales = {}
+
+        def rec(name, t):
+            scales[name] = float(quant.act_scale(t))
+
+        rec("stem", x)
+        h = L.conv2d(params["stem"], x, stride=1, act="relu")
+        for si, blocks in enumerate(params["stages"]):
+            for bi, block in enumerate(blocks):
+                stride = 2 if (si > 0 and bi == 0) else 1
+                identity = h
+                rec(f"s{si}b{bi}.conv1", h)
+                y = L.conv2d(block["conv1"], h, stride=stride, act="relu")
+                rec(f"s{si}b{bi}.conv2", y)
+                y = L.conv2d(block["conv2"], y, stride=1, act=None)
+                if "down" in block:
+                    rec(f"s{si}b{bi}.down", identity)
+                    identity = L.conv2d(block["down"], identity,
+                                        stride=stride, act=None)
+                h = jax.nn.relu(y + identity)
+        rec("fc", jnp.mean(h, axis=(1, 2)))
+        return scales
+
+    def test_calibrate_graph_matches_calibrate_resnet(self, resnet8_int8,
+                                                      resnet18_int8):
+        # calibrate_resnet is calibrate_graph on the ResNet's deployment
+        # graph: the same magnitudes as the replay, from the graph's jitted
+        # float program; XLA rounds the two differently in the last bit
+        # (one scale of ResNet-8's ten on the CPU).
+        for cfg, params, x, scales in (resnet8_int8, resnet18_int8):
+            replay = self._replayed_scales(params, x)
+            assert set(scales) == set(replay)
+            for name, s in replay.items():
+                assert scales[name] == pytest.approx(s, rel=1e-6), name
 
     def test_calibration_scales_cover_layers(self):
         cfg = resnet.RESNET8

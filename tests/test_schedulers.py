@@ -17,6 +17,11 @@ from helpers import build_random_graph, random_graph_st
 
 PAPER_ALGS = ["lblp", "wb", "rr", "rd"]
 ALL_ALGS = [a for a in available() if a != "optimal"]
+#: the schedulers that place the graph as given; ``lblp-r`` replicates
+#: nodes, so the search of ``optimal`` over placements of the
+#: unreplicated graph is no bound for it (``test_replication.py`` holds
+#: it to ``lblp``'s bound instead)
+PLACING_ALGS = [a for a in ALL_ALGS if a != "lblp-r"]
 
 #: profile with generous capacity so random graphs always fit
 ROOMY = HardwareProfile(name="roomy", pu_weight_capacity=1e12)
@@ -226,7 +231,7 @@ class TestOptimal:
         cm = CostModel(ROOMY)
         fleet = make_pus(3, 2)
         b_opt = OptimalScheduler(cm).schedule(g, fleet).bottleneck(g, cm)
-        for alg in ALL_ALGS:
+        for alg in PLACING_ALGS:
             b = get_scheduler(alg, cm).schedule(g, fleet).bottleneck(g, cm)
             assert b_opt <= b * (1 + 1e-9), alg
 
