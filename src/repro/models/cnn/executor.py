@@ -18,12 +18,18 @@ is one launch, whose only host-to-device transfer is the frames: the
 activation scales reach the program as one device vector, made once per
 set of values.  Host frames (a C-contiguous ``np.ndarray`` whose size is a
 multiple of 128) travel as their [rows, 128] view, and the program's first
-step reshapes them back to NHWC, its shape a static argument.  On a TPU
+step lays them out again as NHWC, their shape a static argument.  On a TPU
 that view's default layout (8 x 128 tiles, row-major) is the host buffer's
 own order, so the put is a plain copy; NHWC frames would be laid out batch
 or channel minor, which the runtime does by transposing them on a host
 thread at every put.  A device array, a tracer of an outer ``jit`` or any
-other size goes as it is.  Each node's operations carry its name as a
+other size goes as it is.  The view is laid out by one of two routes,
+chosen from what the call shows (``_planar``): in int8 mode, fewer than
+128 frames of a multiple of 128 pixels each, whose one reader is a CONV
+with a calibrated scale, are quantised on the view and de-interleaved to
+channel-planar order by an exact int8 permutation (``_planar_frames``),
+and that CONV takes them already quantised; every other view goes through
+the batch-minor order (``_nhwc``).  Each node's operations carry its name as a
 ``jax.named_scope``, so device time can be put down to graph nodes.
 ``input_magnitudes``, through which ``quant`` calibrates every model, is
 a second program per graph: the float walk, returning the largest input
@@ -56,8 +62,11 @@ only in a call that traces the program, one ``node`` per graph node in
 topological order (``node`` = the graph's node name, ``kind`` = its
 ``OpKind``), and in int8 mode under each conv and dense node the phase
 spans of ``quant.quantized_conv2d`` and ``quant.quantized_matmul``: these
-time the tracing, not the device.  Counters: ``execute.frames`` (per call),
-``execute.flat_puts`` (calls whose frames went as the [rows, 128] view),
+time the tracing, not the device.  Frames laid out by ``_planar_frames``
+are quantised under a ``quant.act`` span before the walk, so the trace
+still has one ``quant.act`` per conv.  Counters: ``execute.frames`` (per
+call), ``execute.flat_puts`` (calls whose frames went as the [rows, 128] view),
+``execute.planar_puts`` (those of them laid out by ``_planar_frames``),
 ``execute.traces`` (programs traced), and ``execute.bytes_in`` and
 ``execute.bytes_out``, the bytes of ``x`` and of the result, from their
 shapes and dtypes (no wait for the device).
@@ -102,14 +111,16 @@ def execute(g: Graph, params: Dict, x: jnp.ndarray, mode: str = "float",
     obs.count("execute.frames", x.shape[0])
     with obs.span("execute", kind=mode, batch=x.shape[0]):
         names = tuple(sorted(act_scales)) if act_scales else ()
-        shape = None            # device arrays and tracers go as they are
+        shape, planar = None, False  # device arrays and tracers go as they are
         if isinstance(x, np.ndarray) and x.flags.c_contiguous \
                 and x.size % LANES == 0:
             obs.count("execute.flat_puts")
             shape, x = x.shape, x.reshape(-1, LANES)
+            planar = _planar(g, mode, names, shape)
+            obs.count("execute.planar_puts", int(planar))
         out = _program(g, mode, names)(params, x,
                                        _scale_vector(g, act_scales, names),
-                                       shape)
+                                       shape, planar)
     if obs.on():
         obs.count("execute.bytes_in", _nbytes(x))
         obs.count("execute.bytes_out", _nbytes(out))
@@ -181,9 +192,10 @@ def _walk(steps, params, x, mode, scales, index, tapped=(), taps=None):
 
 def _program(g: Graph, mode: str, names: Tuple[str, ...]):
     """The jitted walk of ``g`` in ``mode``, reading the scale of node
-    ``names[i]`` from element ``i`` of its third argument.  Its fourth,
-    static, is the NHWC shape of frames passed as a flat view, or None
-    for frames passed as they are."""
+    ``names[i]`` from element ``i`` of its third argument.  Its fourth and
+    fifth, static, are the NHWC shape of frames passed as a flat view, or
+    None for frames passed as they are, and whether the view is laid out
+    by ``_planar_frames`` (as ``_planar`` decides) or by ``_nhwc``."""
     key = ("executor.program", mode, names)
     program = g.scratch().get(key)
     if program is not None:
@@ -191,14 +203,43 @@ def _program(g: Graph, mode: str, names: Tuple[str, ...]):
     steps = _steps(g)
     index = {name: i for i, name in enumerate(names)}
 
-    def execute_graph(params, x, scales, shape):
+    def execute_graph(params, x, scales, shape, planar):
         obs.count("execute.traces")
-        if shape is not None:
+        if planar:
+            reader = _frame_readers(steps)[0].name
+            with jax.named_scope(reader):
+                x = _planar_frames(x, shape, scales[index[reader]])
+        elif shape is not None:
             x = _nhwc(x, shape)
         return _walk(steps, params, x, mode, scales, index)
 
-    program = g.scratch()[key] = jax.jit(execute_graph, static_argnums=3)
+    program = g.scratch()[key] = jax.jit(execute_graph, static_argnums=(3, 4))
     return program
+
+
+def _frame_readers(steps):
+    """The nodes that read the frames: those with no operands."""
+    return [node for node, ops in steps if not ops]
+
+
+def _planar(g: Graph, mode: str, names: Tuple[str, ...], shape) -> bool:
+    """Whether frames of NHWC ``shape`` put as their flat view are laid out
+    by ``_planar_frames``: in int8 mode, fewer than ``LANES`` frames (in
+    the batch-minor order of ``_nhwc`` such a batch is what is padded to
+    the lanes) of H·W a multiple of ``LANES``, and one reader of the
+    frames, a CONV among ``names`` (calibrated).  The reader is looked for
+    once per graph and ``names``."""
+    n, h, w, _ = shape
+    if mode != "int8" or n >= LANES or h * w % LANES:
+        return False
+    key = ("executor.planar", names)
+    hit = g.scratch().get(key)
+    if hit is None:
+        readers = _frame_readers(_steps(g))
+        hit = g.scratch()[key] = (len(readers) == 1
+                                  and readers[0].kind == OpKind.CONV
+                                  and readers[0].name in names)
+    return hit
 
 
 def _nhwc(flat, shape):
@@ -208,10 +249,36 @@ def _nhwc(flat, shape):
     2-D transpose.  A plain reshape is laid out through [..., 3] padded to
     128 lanes instead: on a TPU v5e 1.24 ms of device time a ResNet-18 call
     of 256 frames, against 0.61 ms this way or with NHWC frames.  With one
-    frame the two are the same program.  Data movement only: the values
-    are unchanged."""
+    frame the two are the same program, and a 640 x 640 frame is re-laid
+    through s8[640,640,3] padded to 128 lanes (0.57 ms of a v5e's time):
+    such small batches take ``_planar_frames`` where ``_planar`` allows,
+    and this route otherwise (float mode, calibration, 128 frames or
+    more).  Data movement only: the values are unchanged."""
     n = shape[0]
     return jnp.moveaxis(flat.reshape(n, -1).T.reshape(*shape[1:], n), -1, 0)
+
+
+def _planar_frames(flat, shape, scale) -> quant.QTensor:
+    """Frames of NHWC ``shape`` from their flat view, quantised with
+    ``scale`` and laid out channel planar, as the frames' reader takes
+    them.  The quantisation is ``quant.quantize_act``'s, elementwise, so
+    it commutes with any reordering; each row of ``LANES`` pixels, its
+    channels interleaved, is then de-interleaved by an int8 product with a
+    0/1 permutation matrix, int32 accumulated: one term a sum, so exact
+    for every input (round, clip and cast have made every pixel, a
+    non-finite one too, an int8).  The planar [N, C, H, W] result is
+    handed on as NHWC, which XLA lays out as it is, no lanes padded."""
+    n, h, w, c = shape
+    with obs.span("quant.act"):
+        q = quant.quantize_act(flat, scale)
+    width = LANES * c                       # a row: LANES pixels, interleaved
+    order = np.arange(width).reshape(LANES, c).T.ravel()
+    perm = np.eye(width, dtype=np.int8)[:, order]   # column j takes order[j]
+    rows = jax.lax.dot(q.q.reshape(-1, width), perm,
+                       preferred_element_type=jnp.int32).astype(jnp.int8)
+    planar = rows.reshape(n, h * w // LANES, c, LANES).transpose(0, 2, 1, 3)
+    return quant.QTensor(planar.reshape(n, c, h, w).transpose(0, 2, 3, 1),
+                         q.scale)
 
 
 def _scale_vector(g: Graph, act_scales, names):

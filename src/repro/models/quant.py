@@ -24,7 +24,9 @@ Spans (``repro.obs``, recorded only inside ``obs.recording()``):
 ``quant.act`` (activation scale if computed, the scale as a float32 array,
 divide, round, clip, cast), ``quant.weight`` (``quantize_weight``),
 ``int8.acc`` (the integer conv or dot with its casts) and ``dequant``
-(cast, optional noise, the two scale multiplies, bias).  Counter:
+(cast, optional noise, the two scale multiplies, bias); an input passed
+already quantised (a ``QTensor``) has no ``quant.act`` here, its
+quantisation being timed where it was made.  Counter:
 ``quant.weight.tensors``, one per weight tensor quantised.  Called eagerly
 they time the device launches; inside a function ``jax.jit`` traces, as
 the graph executor's program, they time the tracing and the counter
@@ -109,10 +111,15 @@ def int8_conv_acc(qx: jnp.ndarray, qw: jnp.ndarray, stride: int = 1,
 
 def _quantize_operands(x, w, x_scale):
     """The ``quant.act`` and ``quant.weight`` phases; ``x_scale`` is a
-    float, a 0-d array or None (then computed from ``x``)."""
-    with obs.span("quant.act"):
-        qx = quantize_act(
-            x, None if x_scale is None else jnp.asarray(x_scale, jnp.float32))
+    float, a 0-d array or None (then computed from ``x``).  An ``x``
+    already quantised, a ``QTensor``, is taken as it is: no ``quant.act``,
+    and ``x_scale`` is not read."""
+    if isinstance(x, QTensor):
+        qx = x
+    else:
+        with obs.span("quant.act"):
+            qx = quantize_act(x, None if x_scale is None
+                              else jnp.asarray(x_scale, jnp.float32))
     with obs.span("quant.weight"):
         obs.count("quant.weight.tensors")
         qw = quantize_weight(w, channel_axis=-1)
@@ -143,13 +150,15 @@ def quantized_matmul(x: jnp.ndarray, w: jnp.ndarray,
     return _dequantize_acc(acc, qx, qw, b, noise_std, key)
 
 
-def quantized_conv2d(x: jnp.ndarray, w: jnp.ndarray,
+def quantized_conv2d(x: Union[jnp.ndarray, QTensor], w: jnp.ndarray,
                      b: Optional[jnp.ndarray] = None,
                      stride: int = 1, padding: str = "SAME",
                      x_scale: Optional[Union[float, jnp.ndarray]] = None,
                      noise_std: float = 0.0,
                      key: Optional[jax.Array] = None) -> jnp.ndarray:
-    """INT8 conv via integer accumulate, NHWC/HWIO."""
+    """INT8 conv via integer accumulate, NHWC/HWIO.  ``x`` is float, or
+    already quantised (a ``QTensor``, as the graph executor passes frames
+    it has quantised and laid out itself)."""
     qx, qw = _quantize_operands(x, w, x_scale)
     with obs.span("int8.acc"):
         acc = int8_conv_acc(qx.q, qw.q, stride, padding)

@@ -48,10 +48,11 @@ SPAN_NAMES = ("execute", "node", "quant.act", "quant.weight", "int8.acc",
 #: frames served, executor programs traced, and weight tensors quantised
 #: (per trace, inside a jitted program); activation scales calibrated; the
 #: bytes of the executor's inputs and results; executor calls whose host
-#: frames were put as a flat [rows, 128] view; summed over the recording
+#: frames were put as a flat [rows, 128] view, and those of them laid out
+#: channel planar in int8; summed over the recording
 COUNTER_NAMES = ("execute.frames", "execute.traces", "quant.weight.tensors",
                  "calibrate.scales", "execute.bytes_in", "execute.bytes_out",
-                 "execute.flat_puts")
+                 "execute.flat_puts", "execute.planar_puts")
 
 _NULL = contextlib.nullcontext()
 _NAN = float("nan")

@@ -330,13 +330,58 @@ class TestCompiledExecutor:
         assert rel <= 1e-5
 
     # (model, batch, frame side): 32x32x3 and 64x64x3 frames are multiples
-    # of 128 floats and are put as a flat view; 30x30x3 (2,700) is not
+    # of 128 floats and are put as a flat view; 30x30x3 (2,700) is not.  In
+    # int8 a view of fewer than 128 frames is laid out channel planar
+    # (``execute.planar_puts``), 256 frames through the batch-minor order
     @pytest.mark.parametrize("mode", ["float", "int8"])
     @pytest.mark.parametrize("model,batch,side", [
         ("resnet8", 1, 32), ("resnet8", 8, 32), ("resnet18", 1, 32),
-        ("resnet18", 8, 32), ("yolov8n", 2, 64), ("resnet8", 1, 30)])
+        ("resnet18", 8, 32), ("yolov8n", 2, 64), ("resnet8", 1, 30),
+        ("yolov8n", 1, 64), ("resnet8", 256, 32)])
     def test_host_frames_match_device_frames(self, request, model, batch,
                                              side, mode):
+        host = np.random.default_rng(batch).standard_normal(
+            (batch, side, side, 3), dtype=np.float32)
+        flat = host.size % executor.LANES == 0
+        planar = flat and mode == "int8" and batch < executor.LANES
+        run = self._runner(request, model, mode)
+
+        on_device, counters = run(jnp.asarray(host))
+        assert counters["execute.flat_puts"] == 0
+        assert counters["execute.planar_puts"] == 0
+        got, counters = run(host)
+        assert counters["execute.flat_puts"] == int(flat)
+        assert counters["execute.planar_puts"] == int(planar)
+        np.testing.assert_array_equal(got, on_device)
+        again, counters = run(host)
+        assert counters["execute.traces"] == 0
+        assert counters["execute.flat_puts"] == int(flat)
+        assert counters["execute.planar_puts"] == int(planar)
+        np.testing.assert_array_equal(again, got)
+
+    @pytest.mark.parametrize("model,side", [
+        ("resnet8", 32), ("resnet18", 32), ("yolov8n", 64)])
+    def test_planar_frames_keep_non_finite_and_saturating_pixels(
+            self, request, model, side):
+        # the planar route quantises before it permutes: +-Inf, NaN and
+        # pixels far beyond the calibrated range reach the conv as the
+        # device-array route's int8 values, and no other pixel is touched
+        host = np.random.default_rng(7).standard_normal(
+            (1, side, side, 3), dtype=np.float32)
+        host[0, 0, 0, 0], host[0, 1, 2, 1], host[0, 3, 5, 2] = \
+            np.inf, -np.inf, np.nan
+        host[0, 9, 4, :] = [1e6, -1e6, 3e38]
+        host[0, side - 1, side - 1, 2] = 1e3
+        run = self._runner(request, model, "int8")
+        got, counters = run(host)
+        assert counters["execute.planar_puts"] == 1
+        on_device, _ = run(jnp.asarray(host))
+        np.testing.assert_array_equal(got, on_device)
+
+    @staticmethod
+    def _runner(request, model, mode):
+        """``execute`` of ``model`` (a fresh graph) in ``mode``, recorded:
+        returns the answer on the host and the counters."""
         from repro import obs
         if model == "yolov8n":
             _, params, _, scales = request.getfixturevalue("yolo64")
@@ -344,9 +389,6 @@ class TestCompiledExecutor:
         else:
             cfg, params, _, scales = request.getfixturevalue(f"{model}_int8")
             g = graphs.build_resnet_graph(cfg)
-        host = np.random.default_rng(batch).standard_normal(
-            (batch, side, side, 3), dtype=np.float32)
-        flat = host.size % executor.LANES == 0
         s = scales if mode == "int8" else None
 
         def run(x):
@@ -355,15 +397,7 @@ class TestCompiledExecutor:
                                                   act_scales=s))
             return out, rec.counters
 
-        on_device, counters = run(jnp.asarray(host))
-        assert counters["execute.flat_puts"] == 0
-        got, counters = run(host)
-        assert counters["execute.flat_puts"] == int(flat)
-        np.testing.assert_array_equal(got, on_device)
-        again, counters = run(host)
-        assert counters["execute.traces"] == 0
-        assert counters["execute.flat_puts"] == int(flat)
-        np.testing.assert_array_equal(again, got)
+        return run
 
     def test_inside_an_outer_jit_twice_leaks_no_tracer(self, resnet8_int8):
         from repro import obs

@@ -12,7 +12,9 @@ The device guard tests run wherever JAX sees no TPU (``JAX_PLATFORMS=cpu``).
 """
 
 import importlib.util
+import math
 import os
+import re
 from pathlib import Path
 
 import jax
@@ -24,7 +26,7 @@ from repro.device import NoTPUError, require_tpu
 from repro.kernels.conv2d import imc_conv2d
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.imc_mvm import imc_mvm
-from repro.models.cnn import executor, graphs, resnet
+from repro.models.cnn import executor, graphs, resnet, yolo
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -105,32 +107,79 @@ def test_int8_executor_compiles_resnet18(one_chip):
     assert compiled.memory_analysis() is not None
 
 
+def _int8_program(one_chip, g, params):
+    """The int8 program of ``g`` with every weighted node calibrated, and
+    a lowering of it to a described chip: ``build(shape, planar)`` for
+    host frames of NHWC ``shape`` put as their [rows, 128] view and laid
+    out as ``planar`` says, ``build(shape)`` for NHWC frames."""
+    names = tuple(sorted(executor.weighted_nodes(g)))
+    program = executor._program(g, "int8", names)
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), params)
+    scales = _spec(one_chip, (len(names),), jnp.float32)
+
+    def build(shape, planar=None):
+        if planar is None:
+            x, shape = _spec(one_chip, shape, jnp.float32), None
+        else:
+            rows = math.prod(shape) // executor.LANES
+            x = _spec(one_chip, (rows, executor.LANES), jnp.float32)
+        return program.lower(params, x, scales, shape, planar).compile()
+
+    return names, build
+
+
+def _resnet18_int8(one_chip):
+    cfg = resnet.RESNET18_CIFAR
+    g = graphs.build_resnet_graph(cfg)
+    return g, _int8_program(one_chip, g, jax.eval_shape(
+        lambda: resnet.init(jax.random.PRNGKey(0), cfg)))
+
+
 @pytest.mark.parametrize("batch", [256, 1])
 def test_served_frames_reach_the_program_in_host_order(one_chip, batch):
     # the int8 ResNet-18 program as served: host frames put as their
     # [rows, 128] view, whose layout must be the host buffer's linear order
     # (row-major, 8 x 128 tiles), so that the put needs no host transpose
-    cfg = resnet.RESNET18_CIFAR
-    g = graphs.build_resnet_graph(cfg)
-    params = jax.tree_util.tree_map(
-        lambda a: _spec(one_chip, a.shape, a.dtype),
-        jax.eval_shape(lambda: resnet.init(jax.random.PRNGKey(0), cfg)))
-    names = tuple(sorted(executor.weighted_nodes(g)))
+    g, (names, build) = _resnet18_int8(one_chip)
     shape = (batch, 32, 32, 3)
-    rows = batch * 32 * 32 * 3 // executor.LANES
-    program = executor._program(g, "int8", names)
-    scales = _spec(one_chip, (len(names),), jnp.float32)
-    flat = program.lower(
-        params, _spec(one_chip, (rows, executor.LANES), jnp.float32),
-        scales, shape).compile()
+    flat = build(shape, executor._planar(g, "int8", names, shape))
     layout = flat.input_formats[0][1].layout
     assert layout.major_to_minor == (0, 1)
     assert layout.tiling == ((8, 128),)
     # the device re-lays the frames at little cost: within 5% of the bytes
     # the program accesses with NHWC frames (a plain reshape: +15% at 256)
-    nhwc = program.lower(params, _spec(one_chip, shape, jnp.float32),
-                         scales, None).compile()
-    assert _bytes_accessed(flat) <= 1.05 * _bytes_accessed(nhwc)
+    assert _bytes_accessed(flat) <= 1.05 * _bytes_accessed(build(shape))
+
+
+def test_planar_frames_access_no_more_than_the_batch_minor_route(one_chip):
+    # below 128 frames the view is laid out channel planar: at 8 ResNet-18
+    # frames that reads and writes no more than the batch-minor route
+    g, (names, build) = _resnet18_int8(one_chip)
+    shape = (8, 32, 32, 3)
+    assert executor._planar(g, "int8", names, shape)
+    assert _bytes_accessed(build(shape, True)) \
+        <= _bytes_accessed(build(shape, False))
+
+
+def test_a_single_frame_is_not_relaid_through_padded_lanes(one_chip):
+    # YOLOv8n's one int8 frame, 128 x 128: through the batch-minor route
+    # XLA reshapes the quantised s8[128,1,128,3] into 3 of 128 lanes and
+    # copies it out again (1.028x the bytes of NHWC frames); laid out
+    # channel planar no reshape or copy of the frame's shape is left
+    side = 128
+    cfg = dict(yolo.YOLOV8N, image_hw=(side, side))
+    g = graphs.build_yolov8n_graph(cfg)
+    names, build = _int8_program(one_chip, g, jax.eval_shape(
+        lambda: yolo.init(jax.random.PRNGKey(0), cfg)))
+    shape = (1, side, side, 3)
+    assert executor._planar(g, "int8", names, shape)
+    served = build(shape, True)
+    assert _bytes_accessed(served) <= 1.02 * _bytes_accessed(build(shape))
+    relaid = [m.group(0) for m in re.finditer(
+        r"= s8\[([0-9,]*),3\]\S* (reshape|copy)\(", served.as_text())
+        if 3 * math.prod(map(int, m.group(1).split(","))) == math.prod(shape)]
+    assert relaid == []
 
 
 def _bytes_accessed(compiled):
