@@ -26,7 +26,9 @@ DPU model (digital elementwise/pool/move engine)
 ------------------------------------------------
     t_dpu(node) = out_elems / elem_rate + t_setup
 conv/MVM *can* run on a DPU at ``dpu_mac_rate`` MAC/s (paper: "functions
-similar to IMC-PUs are also supported but with lower performance").
+similar to IMC-PUs are also supported but with lower performance").  A
+PRODUCT of two activations (attention) is MAC work with no stationary
+weights: priced the same way from its ``flops``, on a DPU only.
 
 Transfers (compute-and-forward over shared DRAM / IPI)
 ------------------------------------------------------
@@ -153,6 +155,8 @@ class CostModel:
         # digital ops; IMC PUs cannot run them at all.
         if pu_type is PUType.IMC:
             return math.inf
+        if node.kind is OpKind.PRODUCT:
+            return node.flops / p.dpu_mac_rate + p.dpu_setup
         return node.out_elems / p.dpu_elem_rate + p.dpu_setup
 
     def frame_time(self, node: Node, pu_type: Optional[PUType] = None,

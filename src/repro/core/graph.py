@@ -37,13 +37,16 @@ class OpKind(enum.Enum):
     """Functional class of a graph node.
 
     ``CONV``/``MVM`` are the in-memory-computable kinds; the rest are
-    digital ops served by DPUs (paper §IV, first paragraph).
+    digital ops served by DPUs (paper §IV, first paragraph).  ``PRODUCT``
+    multiplies two activations (attention's q·kᵀ and v·attnᵀ): neither
+    operand is a stationary weight a crossbar could hold, so it is digital.
     Activations (ReLU/SiLU) are *fused* into their producer conv/MVM, as
     in the IMCE PUs ("optionally followed by activation functions").
     """
 
     CONV = "conv"
     MVM = "mvm"                 # fully-connected / matmul
+    PRODUCT = "product"         # contraction of two activations
     ADD = "add"
     MUL = "mul"
     POOL_MAX = "pool_max"

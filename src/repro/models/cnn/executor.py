@@ -42,19 +42,22 @@ Two arithmetic modes:
   matching the paper's INT8 deployment.
 
 Numerics parity with the un-scheduled reference model is asserted in
-tests (float mode: ``resnet.forward`` and ``yolo.forward`` within float
-rounding; int8 mode: bounded quantization error).
+tests (float mode: ``resnet.forward``, ``yolo.forward`` and
+``yolo11.forward`` within float rounding; int8 mode: bounded quantization
+error).
 
 Node kinds: CONV, MVM, ADD and GLOBAL_POOL (the ResNet graphs); ACT, MUL,
-CONCAT, SPLIT, POOL_MAX, UPSAMPLE, RESHAPE and SOFTMAX (YOLOv8n;
-``graphs.py`` documents their metadata); INPUT (the frames) and OUTPUT
-(its operand).  In int8 mode only the nodes with learned weights are
-quantised: each CONV's input per tensor and its weights per output
-channel, and each MVM with a ``param``.  Everything else runs in float32
-by design, inside the same program: YOLOv8n's SiLU (ACT + MUL), residual
-adds, concats, pools, upsamples and its whole decode (the DFL softmax,
-the fixed-weight ``dfl.conv``, dist2bbox and the class sigmoid), none of
-which has learned weights.
+CONCAT, SPLIT, POOL_MAX, UPSAMPLE, RESHAPE and SOFTMAX (YOLOv8n); grouped
+CONVs and PRODUCT, the contraction of two activations (YOLO11n's
+depthwise convs and C2PSA attention; ``graphs.py`` documents their
+metadata); INPUT (the frames) and OUTPUT (its operand).  In int8 mode only
+the nodes with learned weights are quantised: each CONV's input per tensor
+and its weights per output channel, grouped or not, and each MVM with a
+``param``.  Everything else runs in float32 by design, inside the same
+program: the detectors' SiLU (ACT + MUL), residual adds, concats, pools,
+upsamples, the attention's products (at ``HIGHEST``) and softmax, and the
+whole decode (the DFL softmax, the fixed-weight ``dfl.conv``, dist2bbox
+and the class sigmoid), none of which has learned weights.
 
 Spans (``repro.obs``, recorded only inside ``obs.recording()``): one
 ``execute`` per call (``kind`` = the mode, ``batch`` = frames).  Under it,
@@ -69,7 +72,12 @@ call), ``execute.flat_puts`` (calls whose frames went as the [rows, 128] view),
 ``execute.planar_puts`` (those of them laid out by ``_planar_frames``),
 ``execute.traces`` (programs traced), and ``execute.bytes_in`` and
 ``execute.bytes_out``, the bytes of ``x`` and of the result, from their
-shapes and dtypes (no wait for the device).
+shapes and dtypes (no wait for the device).  Two count what a trace puts
+into a program, once per compiled program and not per call:
+``execute.act_products``, the PRODUCT nodes traced (2 for YOLO11n, one
+q·kᵀ and one v·attnᵀ, each over both heads), and ``quant``'s
+``quant.grouped_convs``, the grouped int8 convs (7 for YOLO11n in int8
+mode); both read 0 for YOLOv8n and the ResNets.
 """
 
 from __future__ import annotations
@@ -302,13 +310,20 @@ def _run_node(node, ins, params, x, mode, x_scale):
     if node.kind == OpKind.CONV:
         inp = ins[0] if ins else x
         p = _param_at(params, meta["param"])
+        groups = meta.get("groups", 1)
         if mode == "int8":
             y = quant.quantized_conv2d(
                 inp, p["w"], p["b"], stride=meta["stride"],
-                padding=meta["padding"], x_scale=x_scale)
+                padding=meta["padding"], x_scale=x_scale,
+                feature_group_count=groups)
             return L.activate(y, meta.get("act"))
         return L.conv2d(p, inp, stride=meta["stride"],
-                        padding=meta["padding"], act=meta.get("act"))
+                        padding=meta["padding"], act=meta.get("act"),
+                        groups=groups)
+    if node.kind == OpKind.PRODUCT:         # no learned weights: float32
+        obs.count("execute.act_products")
+        return jnp.einsum(meta["einsum"], *ins,
+                          precision=jax.lax.Precision.HIGHEST)
     if node.kind == OpKind.MVM:
         if meta.get("param") is None:       # fixed weights, float32 in any mode
             return jnp.matmul(ins[0], jnp.asarray(meta["weights"], jnp.float32),

@@ -50,12 +50,14 @@ def dense_init(key, cin: int, cout: int) -> Dict[str, jnp.ndarray]:
 # ---------------------------------------------------------------------------
 
 def conv2d(params, x: jnp.ndarray, stride: int = 1, padding="SAME",
-           act: Optional[str] = None) -> jnp.ndarray:
+           act: Optional[str] = None, groups: int = 1) -> jnp.ndarray:
+    """NHWC conv with HWIO weights ``[k, k, cin / groups, cout]``."""
     y = jax.lax.conv_general_dilated(
         x, params["w"],
         window_strides=(stride, stride),
         padding=padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=groups,
     )
     y = y + params["b"]
     return activate(y, act)
@@ -131,17 +133,20 @@ def conv_out_hw(h: int, w: int, k: int, stride: int, padding) -> Tuple[int, int]
 
 
 def conv_cost(h: int, w: int, k: int, cin: int, cout: int, stride: int,
-              padding="SAME") -> dict:
-    """FLOPs/bytes/IMC-metadata for one conv node (per single frame)."""
+              padding="SAME", groups: int = 1) -> dict:
+    """FLOPs/bytes/IMC-metadata for one conv node (per single frame).  Each
+    of ``groups`` groups maps ``cin / groups`` inputs to ``cout / groups``
+    outputs, so a weight column reads ``k * k * cin / groups`` values."""
     ho, wo = conv_out_hw(h, w, k, stride, padding)
-    macs = ho * wo * k * k * cin * cout
-    params = k * k * cin * cout + cout
+    cin_kk = k * k * cin // groups
+    macs = ho * wo * cin_kk * cout
+    params = cin_kk * cout + cout
     return {
         "flops": 2.0 * macs,
         "weight_bytes": float(params),            # INT8 deployment: 1 B/param
         "out_bytes": float(ho * wo * cout),       # INT8 activations
         "out_elems": float(ho * wo * cout),
-        "meta": {"cin_kk": k * k * cin, "cout": cout, "n_vectors": ho * wo,
+        "meta": {"cin_kk": cin_kk, "cout": cout, "n_vectors": ho * wo,
                  "out_hw": (ho, wo)},
     }
 

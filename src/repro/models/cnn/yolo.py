@@ -124,8 +124,10 @@ def autopad(k: int):
 
 
 def _conv(p, x, stride=1, act="silu"):
+    """A conv padded ``autopad``; grouped where its HWIO weights take fewer
+    input channels than ``x`` has (a depthwise conv's take one)."""
     return L.conv2d(p, x, stride=stride, padding=autopad(p["w"].shape[0]),
-                    act=act)
+                    act=act, groups=x.shape[-1] // p["w"].shape[2])
 
 
 def _c2f(p, x, shortcut: bool):
@@ -187,11 +189,14 @@ def forward(params, x, cfg: dict = YOLOV8N, decode: bool = True):
         box = _head_branch(params["head"]["cv2"][i], f)
         cls = _head_branch(params["head"]["cv3"][i], f)
         raw.append(jnp.concatenate([box, cls], axis=-1))
-    if not decode:
-        return raw
+    return decode_raw(raw) if decode else raw
 
-    # DFL decode + dist2bbox (the 24 post-processing ONNX nodes)
-    b = x.shape[0]
+
+def decode_raw(raw):
+    """Detect's decode of each scale's (B, h, w, 4 * REG_MAX + NC) output
+    (P3 first): DFL, dist2bbox and the class sigmoid -> (B, anchors,
+    4 + NC).  YOLOv8 and YOLO11 decode alike."""
+    b = raw[0].shape[0]
     flat, anchors, strides = [], [], []
     for f, s in zip(raw, STRIDES):
         _, h, w, c = f.shape

@@ -26,11 +26,12 @@ divide, round, clip, cast), ``quant.weight`` (``quantize_weight``),
 ``int8.acc`` (the integer conv or dot with its casts) and ``dequant``
 (cast, optional noise, the two scale multiplies, bias); an input passed
 already quantised (a ``QTensor``) has no ``quant.act`` here, its
-quantisation being timed where it was made.  Counter:
-``quant.weight.tensors``, one per weight tensor quantised.  Called eagerly
+quantisation being timed where it was made.  Counters:
+``quant.weight.tensors``, one per weight tensor quantised, and
+``quant.grouped_convs``, one per grouped conv.  Called eagerly
 they time the device launches; inside a function ``jax.jit`` traces, as
-the graph executor's program, they time the tracing and the counter
-counts tensors quantised per trace, once per compiled program.
+the graph executor's program, they time the tracing and the counters
+count per trace, once per compiled program.
 ``calibrate_graph`` records one ``calibrate`` span (``node`` = the graph's
 name, ``kind`` = "<n> nodes", ``batch`` = the calibration frames) and
 counts the scales it makes in ``calibrate.scales``.
@@ -99,12 +100,15 @@ def int8_matmul_acc(qx: jnp.ndarray, qw: jnp.ndarray) -> jnp.ndarray:
 
 
 def int8_conv_acc(qx: jnp.ndarray, qw: jnp.ndarray, stride: int = 1,
-                  padding: str = "SAME") -> jnp.ndarray:
-    """INT8 NHWC x HWIO conv -> INT32 exact accumulation."""
+                  padding: str = "SAME",
+                  feature_group_count: int = 1) -> jnp.ndarray:
+    """INT8 NHWC x HWIO conv -> INT32 exact accumulation; grouped, the
+    weights are ``[k, k, cin / feature_group_count, cout]``."""
     return jax.lax.conv_general_dilated(
         qx.astype(jnp.int32), qw.astype(jnp.int32),
         window_strides=(stride, stride), padding=padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=feature_group_count,
         preferred_element_type=jnp.int32,
     )
 
@@ -155,13 +159,19 @@ def quantized_conv2d(x: Union[jnp.ndarray, QTensor], w: jnp.ndarray,
                      stride: int = 1, padding: str = "SAME",
                      x_scale: Optional[Union[float, jnp.ndarray]] = None,
                      noise_std: float = 0.0,
-                     key: Optional[jax.Array] = None) -> jnp.ndarray:
+                     key: Optional[jax.Array] = None,
+                     feature_group_count: int = 1) -> jnp.ndarray:
     """INT8 conv via integer accumulate, NHWC/HWIO.  ``x`` is float, or
     already quantised (a ``QTensor``, as the graph executor passes frames
-    it has quantised and laid out itself)."""
+    it has quantised and laid out itself).  Grouped (``feature_group_count``
+    > 1, a depthwise conv where it equals the input channels), ``w`` is
+    ``[k, k, cin / feature_group_count, cout]``, its scales still one per
+    output channel."""
     qx, qw = _quantize_operands(x, w, x_scale)
+    if feature_group_count > 1:
+        obs.count("quant.grouped_convs")
     with obs.span("int8.acc"):
-        acc = int8_conv_acc(qx.q, qw.q, stride, padding)
+        acc = int8_conv_acc(qx.q, qw.q, stride, padding, feature_group_count)
     return _dequantize_acc(acc, qx, qw, b, noise_std, key)
 
 

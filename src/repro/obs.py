@@ -49,10 +49,12 @@ SPAN_NAMES = ("execute", "node", "quant.act", "quant.weight", "int8.acc",
 #: (per trace, inside a jitted program); activation scales calibrated; the
 #: bytes of the executor's inputs and results; executor calls whose host
 #: frames were put as a flat [rows, 128] view, and those of them laid out
-#: channel planar in int8; summed over the recording
+#: channel planar in int8; grouped int8 convs and products of two
+#: activations traced into a program; summed over the recording
 COUNTER_NAMES = ("execute.frames", "execute.traces", "quant.weight.tensors",
                  "calibrate.scales", "execute.bytes_in", "execute.bytes_out",
-                 "execute.flat_puts", "execute.planar_puts")
+                 "execute.flat_puts", "execute.planar_puts",
+                 "quant.grouped_convs", "execute.act_products")
 
 _NULL = contextlib.nullcontext()
 _NAN = float("nan")

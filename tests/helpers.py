@@ -14,7 +14,7 @@ import random
 import pytest
 
 try:
-    from hypothesis import given, settings
+    from hypothesis import example, given, settings
     from hypothesis import strategies as st
 
     HAVE_HYPOTHESIS = True
@@ -54,10 +54,12 @@ except ModuleNotFoundError:  # degrade gracefully: collect, then skip
 
         return deco
 
+    example = settings
+
 
 from repro.core.graph import Graph, OpKind
 
-__all__ = ["given", "settings", "st", "HAVE_HYPOTHESIS",
+__all__ = ["example", "given", "settings", "st", "HAVE_HYPOTHESIS",
            "build_random_graph", "random_graph_st"]
 
 IMC_OPS = [OpKind.CONV, OpKind.MVM]

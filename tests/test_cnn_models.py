@@ -5,11 +5,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from helpers import given, settings, st
+from helpers import example, given, settings, st
 
 from repro.core.graph import OpKind, PUType
 from repro.models import quant
-from repro.models.cnn import executor, graphs, resnet, yolo
+from repro.models.cnn import executor, graphs, resnet, yolo, yolo11
 from repro.models.cnn import layers as L
 from repro.models.cnn.layers import count_params
 
@@ -424,8 +424,124 @@ class TestCompiledExecutor:
                 assert f"/{g.nodes[n].name}/" in hlo, g.nodes[n].name
 
 
+YOLO11_64 = dict(yolo11.YOLO11N, image_hw=(64, 64))
+
+
+@pytest.fixture(scope="module")
+def yolo11_64():
+    """YOLO11n's graph at 64x64, seeded ``yolo11.init`` weights, 2 frames
+    and their scales from ``quant.calibrate_graph``."""
+    params = yolo11.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, 64, 3))
+    g = graphs.build_yolo11n_graph(YOLO11_64)
+    return g, params, x, quant.calibrate_graph(g, params, x)
+
+
+class TestYolo11n:
+    def test_counts_match_the_published_model(self):
+        # Ultralytics publishes 2.6 M parameters and 6.5 GFLOPs (2 x MACs)
+        g = graphs.build_yolo11n_graph()
+        convs = [n for n in g.nodes.values() if n.kind == OpKind.CONV]
+        assert len(convs) == 87
+        grouped = [n for n in convs if n.meta.get("groups", 1) != 1]
+        assert len(grouped) == 7
+        for n in grouped:       # depthwise: groups == cin (one input each)
+            assert n.meta["cin_kk"] == n.meta["k"] ** 2
+            assert n.meta["groups"] == n.meta["cout"]
+        assert sum(n.flops for n in convs) == 2 * 3_239_993_600
+        products = [n for n in g.nodes.values() if n.kind == OpKind.PRODUCT]
+        assert [n.name for n in products] == ["b10.m0.attn.qk",
+                                              "b10.m0.attn.av"]
+        assert sum(n.flops for n in products) == 2 * 30_720_000
+        assert sum(n.weight_bytes for n in convs) == 2_616_232
+        assert yolo11.num_params() == 2_616_232
+
+    def test_every_conv_param_resolves(self, yolo11_64):
+        g, params, _, _ = yolo11_64
+        convs = [n for n in g.nodes.values() if n.kind == OpKind.CONV]
+        for n in convs:
+            p = executor._param_at(params, n.meta["param"])
+            k, cout = n.meta["k"], n.meta["cout"]
+            assert p["w"].shape == (k, k, n.meta["cin_kk"] // (k * k), cout)
+            assert p["b"].shape == (cout,)
+        assert len(jax.tree_util.tree_leaves(params)) == 2 * len(convs) == 174
+
+    def test_float_execution_matches_reference(self, yolo11_64):
+        g, params, x, _ = yolo11_64
+        got = executor.execute(g, params, x, mode="float")
+        assert got.shape == (2, 8 * 8 + 4 * 4 + 2 * 2, 4 + yolo.NC)
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(yolo11.forward(params, x)),
+                                   rtol=1e-5, atol=1e-5)
+
+    # The same measure and bound as YOLOv8n's (TestExecutorParity): per-
+    # tensor int8 rounding at the 87 conv inputs, the products and softmax
+    # in float32, reads about 0.13 (box) and 0.17 (cls) on these 2 frames;
+    # int4 weights read about 1 in at least one part.
+    def test_int8_parts_close_to_float(self, yolo11_64):
+        g, params, x, scales = yolo11_64
+        f32 = executor.execute(g, params, x, mode="float")
+        i8 = executor.execute(g, params, x, mode="int8", act_scales=scales)
+        err = TestExecutorParity._part_errors(i8, f32)
+        bound = TestExecutorParity.PART_BOUND
+        assert all(err[p] < b for p, b in bound.items()), err
+
+    def test_int4_weights_fail_a_part_bound(self, yolo11_64):
+        g, params, x, scales = yolo11_64
+
+        def int4(path, a):
+            if path[-1].key != "w":
+                return a
+            s = jnp.maximum(jnp.max(jnp.abs(a), axis=(0, 1, 2)), 1e-8) / 7
+            return jnp.clip(jnp.round(a / s), -7, 7) * s
+
+        p4 = jax.tree_util.tree_map_with_path(int4, params)
+        f32 = executor.execute(g, params, x, mode="float")
+        i4 = executor.execute(g, p4, x, mode="int8", act_scales=scales)
+        err = TestExecutorParity._part_errors(i4, f32)
+        bound = TestExecutorParity.PART_BOUND
+        assert any(err[p] >= b for p, b in bound.items()), err
+
+    def test_lblp_puts_every_product_on_a_dpu(self):
+        from repro.core import CostModel, get_scheduler, make_pus
+        g = graphs.build_yolo11n_graph()
+        fleet = make_pus(8, 4)
+        kind = {p.pu_id: p.pu_type for p in fleet}
+        placed = get_scheduler("lblp", CostModel()).schedule(g, fleet).mapping
+        products = [n for n, node in g.nodes.items()
+                    if node.kind == OpKind.PRODUCT]
+        assert len(products) == 2
+        assert all(kind[placed[n]] == PUType.DPU for n in products)
+        assert all(kind[placed[n]] == PUType.IMC for n, node in g.nodes.items()
+                   if node.kind == OpKind.CONV)
+
+    def test_counters_read_once_per_compiled_program(self, yolo11_64, yolo64,
+                                                     resnet8_int8):
+        from repro import obs
+        cfg, r_params, r_x, r_scales = resnet8_int8
+        fresh = [  # new graphs: their programs are traced in the recording
+            (graphs.build_yolo11n_graph(YOLO11_64), *yolo11_64[1:]),
+            (graphs.build_yolov8n_graph(YOLO64), *yolo64[1:]),
+            (graphs.build_resnet_graph(cfg), r_params, r_x, r_scales)]
+        read = []
+        for g, params, x, scales in fresh:
+            with obs.recording() as rec:
+                for _ in range(2):          # the second call traces nothing
+                    out = executor.execute(g, params, x[:1], mode="int8",
+                                           act_scales=scales)
+            assert np.isfinite(np.asarray(out)).all()
+            read.append((rec.counters["quant.grouped_convs"],
+                         rec.counters["execute.act_products"]))
+            r = rec.rows()
+            nodes = r["name"] == obs.SPAN_NAMES.index("node")
+            assert nodes.sum() == len(g)    # every node ran under its span
+        assert read == [(7, 2), (0, 0), (0, 0)]
+
+
 class TestQuant:
     @given(st.integers(0, 1000), st.integers(1, 6), st.integers(1, 64))
+    @example(seed=387, rows=6, cols=64)
+    @example(seed=758, rows=6, cols=64)
     @settings(max_examples=30, deadline=None)
     def test_weight_roundtrip_error_bound(self, seed, rows, cols):
         key = jax.random.PRNGKey(seed)
@@ -433,10 +549,17 @@ class TestQuant:
             jax.random.uniform(key, (1, cols), minval=0.1, maxval=10.0)
         qt = quant.quantize_weight(w, channel_axis=-1)
         back = quant.dequantize(qt, channel_axis=-1)
-        # per-channel error bounded by scale/2 per element
-        err = jnp.abs(back - w)
-        bound = qt.scale[None, :] * 0.5 + 1e-7
-        assert bool(jnp.all(err <= bound))
+        # Per element, in exact (float64) arithmetic: half a step s / 2 from
+        # rounding to the integer q, plus float32's rounding (unit roundoff
+        # 2^-24) of the division w / s, at most 2^-24 |w|, and of the
+        # product q * s, at most 2^-24 |q s|.  The two draws pinned above
+        # exceed s / 2 alone, by 7.6e-8 and 7.5e-9.
+        w64 = np.asarray(w, np.float64)
+        s64 = np.asarray(qt.scale, np.float64)[None, :]
+        qs = np.abs(np.asarray(qt.q, np.float64)) * s64
+        err = np.abs(np.asarray(back, np.float64) - w64)
+        bound = 0.5 * s64 + 2.0 ** -24 * (np.abs(w64) + qs)
+        assert (err <= bound).all(), (err - bound).max()
 
     @given(st.integers(0, 1000))
     @settings(max_examples=20, deadline=None)
@@ -459,6 +582,28 @@ class TestQuant:
         ref = jax.lax.conv_general_dilated(
             x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
         got = quant.quantized_conv2d(x, w, b)
+        rel = jnp.linalg.norm(got - ref) / jnp.linalg.norm(ref)
+        assert rel < 0.05
+
+    def test_grouped_conv_accumulates_each_group_exactly(self):
+        # a depthwise int8 conv is, channel by channel, the dense int8 conv
+        # of that channel alone
+        k1, k2 = jax.random.split(jax.random.PRNGKey(3))
+        qx = jax.random.randint(k1, (2, 9, 9, 6), -127, 128).astype(jnp.int8)
+        qw = jax.random.randint(k2, (3, 3, 1, 6), -127, 128).astype(jnp.int8)
+        acc = quant.int8_conv_acc(qx, qw, 2, "SAME", feature_group_count=6)
+        per = [quant.int8_conv_acc(qx[..., c:c + 1], qw[..., c:c + 1], 2,
+                                   "SAME") for c in range(6)]
+        np.testing.assert_array_equal(np.asarray(acc),
+                                      np.asarray(jnp.concatenate(per, -1)))
+
+    def test_quantized_grouped_conv_close(self):
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 16, 8))
+        w = jax.random.normal(jax.random.PRNGKey(1), (3, 3, 2, 12)) * 0.2
+        b = jnp.zeros((12,))
+        ref = L.conv2d({"w": w, "b": b}, x, groups=4)
+        got = quant.quantized_conv2d(x, w, b, feature_group_count=4)
+        assert quant.weight_scale(w).shape == (12,)    # per output channel
         rel = jnp.linalg.norm(got - ref) / jnp.linalg.norm(ref)
         assert rel < 0.05
 

@@ -47,6 +47,20 @@ class TestBasics:
         assert Node(2, "m", OpKind.MVM).pu_type is PUType.IMC
         assert Node(3, "a", OpKind.ADD).pu_type is PUType.DPU
         assert Node(4, "p", OpKind.POOL_MAX).pu_type is PUType.DPU
+        # two activations multiplied: no stationary weights for a crossbar
+        assert Node(5, "qk", OpKind.PRODUCT).pu_type is PUType.DPU
+
+    def test_activation_product_priced_by_its_macs(self):
+        cm = CostModel()
+        p = cm.profile
+        # attention scores of 2 heads over 400 tokens: 400 x 400 x 2
+        # elements, each a sum of 32 products
+        node = Node(1, "qk", OpKind.PRODUCT, flops=2.0 * 10_240_000,
+                    out_elems=320_000.0)
+        assert cm.time(node) == pytest.approx(
+            2.0 * 10_240_000 / p.dpu_mac_rate + p.dpu_setup)
+        assert cm.time(node) > 320_000.0 / p.dpu_elem_rate + p.dpu_setup
+        assert cm.time(node, PUType.IMC) == float("inf")
 
     def test_json_roundtrip(self):
         g = build_random_graph(12, 0.3, seed=7)

@@ -142,7 +142,9 @@ def test_warm_call_records_its_execute_span_alone(resnet8, recorded):
                             "quant.weight.tensors": 0, "calibrate.scales": 0,
                             "execute.bytes_in": 2 * 32 * 32 * 3 * 4,
                             "execute.bytes_out": 2 * 10 * 4,
-                            "execute.flat_puts": 0, "execute.planar_puts": 0}
+                            "execute.flat_puts": 0, "execute.planar_puts": 0,
+                            "quant.grouped_convs": 0,
+                            "execute.act_products": 0}
 
 
 def test_bytes_in_and_out_counted_from_shapes_per_call(resnet8, recorded):
